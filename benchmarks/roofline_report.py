@@ -15,24 +15,23 @@ from typing import Dict, List
 RESULTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "results", "dryrun")
 
-# Nominal main-memory bandwidth per device, bytes/s.  These are coarse
-# reference points (DDR4 dual-channel, an A100-class HBM part, a TPU-v4
-# class part), good enough to say "this kernel runs at X% of a sane peak"
-# in a bench row; override with REPRO_PEAK_BYTES_PER_S for real hardware.
-NOMINAL_PEAK_BYTES_PER_S = {
-    "cpu": 25.6e9,
-    "gpu": 2.0e12,
-    "tpu": 1.2e12,
+# Published HBM bandwidth per chip, bytes/s, keyed by jax's device_kind,
+# with the source of each entry.  A device missing here is an error, not a
+# default: a roofline share against a guessed peak is not a measurement.
+PEAK_BYTES_PER_S = {
+    # Google Cloud documentation, "TPU v5e": 16 GB HBM2 at 819 GB/s
+    "TPU v5 lite": (819e9, "Google Cloud documentation, TPU v5e"),
 }
 
 
 def bytes_bound(bytes_per_call: float, seconds_per_call: float,
-                platform: str = None) -> Dict:
+                device_kind: str = None) -> Dict:
     """Achieved-vs-peak memory-bandwidth verdict for one timed kernel.
 
     `bytes_per_call` comes from the hlo_cost census of the compiled
-    module; the peak is the nominal per-platform table above unless
-    REPRO_PEAK_BYTES_PER_S overrides it.  Returns the achieved bandwidth,
+    module; the peak is the published one for `device_kind` (default:
+    the first device JAX reports), and a device kind missing from
+    PEAK_BYTES_PER_S raises KeyError.  Returns the achieved bandwidth,
     the peak used, the fraction of peak, and the roofline floor (the
     wall-clock the transfer alone would take at peak) — the fields
     benchmarks/run.py attaches to kernel rows.
@@ -44,24 +43,23 @@ def bytes_bound(bytes_per_call: float, seconds_per_call: float,
     being served from cache, not that the hardware beat its roofline;
     values << 1 mean the kernel genuinely has bandwidth headroom.
     """
-    if platform is None:
-        try:
-            import jax
-            platform = jax.default_backend()
-        except Exception:
-            platform = "cpu"
-    env = os.environ.get("REPRO_PEAK_BYTES_PER_S")
-    peak = (float(env) if env
-            else NOMINAL_PEAK_BYTES_PER_S.get(platform,
-                                              NOMINAL_PEAK_BYTES_PER_S["cpu"]))
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in PEAK_BYTES_PER_S:
+        raise KeyError(
+            f"no published HBM bandwidth on record for device kind "
+            f"{device_kind!r}; add it to PEAK_BYTES_PER_S with its source")
+    peak, source = PEAK_BYTES_PER_S[device_kind]
     achieved = bytes_per_call / seconds_per_call if seconds_per_call else 0.0
     return {
         "bytes_per_call": float(bytes_per_call),
         "achieved_bytes_per_s": achieved,
+        "device_kind": device_kind,
         "peak_bytes_per_s": peak,
-        "peak_source": "env" if env else f"nominal:{platform}",
-        "fraction_of_peak": achieved / peak if peak else 0.0,
-        "memory_bound_floor_s": bytes_per_call / peak if peak else 0.0,
+        "peak_source": source,
+        "fraction_of_peak": achieved / peak,
+        "memory_bound_floor_s": bytes_per_call / peak,
     }
 
 

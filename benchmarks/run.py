@@ -83,8 +83,9 @@ def _kernel_bench(quick: bool = False):
     jax.block_until_ready(grad)
     dt = (time.perf_counter() - t0) / n
     # achieved-vs-peak bytes bound: hlo_cost census of the compiled module
-    # against the roofline_report peak table (REPRO_PEAK_BYTES_PER_S
-    # overrides the nominal per-platform number)
+    # against the roofline_report peak table (published peaks by
+    # device_kind; a device missing there — the CPU included — leaves the
+    # row without a bound and says why)
     from repro.launch import hlo_cost
     from . import roofline_report
     try:
@@ -230,6 +231,8 @@ def main() -> None:
     ap.add_argument("--no-history", action="store_true",
                     help="skip the bench_history.jsonl append for this run")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     _register()
     if args.list:
         _list_suites()

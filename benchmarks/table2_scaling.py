@@ -2,6 +2,9 @@
 this solver, across problem sizes; plus multi-shard scaling (subprocess with
 8 virtual host devices — wall-clock on 1 physical core measures partitioning
 overhead honestly; real scaling is the dry-run's collective analysis).
+The shard-scaling children run on the CPU by design (JAX_PLATFORMS=cpu is
+set for them, and their rows say so): the parent holds JAX, so a child
+could never reach an accelerator the parent already holds.
 
 Paper claim reproduced: >= 10x per-iteration speedup over the prior CPU
 solver under matched stopping criterion (same AGD math, same instance).
@@ -77,6 +80,7 @@ def run_shard_scaling(quick: bool = False):
     I = 50_000
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     finals = {}
     for shards in ([1, 4] if quick else [1, 2, 4, 8]):
@@ -88,7 +92,8 @@ def run_shard_scaling(quick: bool = False):
         rows.append({
             "name": f"table2/shard_scaling/I={I}/shards={shards}",
             "us_per_call": data["per_iter_s"] * 1e6,
-            "derived": {"final_dual": data["final_dual"]},
+            "derived": {"final_dual": data["final_dual"],
+                        "platform": "cpu"},
         })
     # all shard counts converge to the same optimum (Fig.1-style invariance)
     vals = np.array(list(finals.values()))

@@ -37,7 +37,6 @@ import numpy as np
 def launch(args, extra, log_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
-    env.setdefault("JAX_PLATFORMS", "cpu")
     base = [sys.executable, "-m", "repro.launch.solve",
             "--sources", str(args.sources), "--destinations", "50",
             "--iterations", str(args.iterations),

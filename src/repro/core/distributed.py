@@ -30,18 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from . import objectives
 from .maximizer import _infeas_scale, maximize
 from .types import (AxPlan, HealthConfig, LPData, Slab, SolveConfig,
-                    SolveResult, SolveState, StoppingCriteria)
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map: jax.shard_map(check_vma=) on new jax,
-    jax.experimental.shard_map.shard_map(check_rep=) on older releases."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+                    SolveResult, SolveState, StoppingCriteria, edge_space)
 
 
 def pad_slab_rows(slab: Slab, multiple: int) -> Slab:
@@ -190,7 +179,7 @@ class DistributedMatchingObjective:
                 for slab in slabs:
                     x, c_s, sq_s = objectives.slab_xcarry(
                         slab, lam_full, gamma, kind, iters, pallas)
-                    parts.append(x.reshape(-1))
+                    parts.append(edge_space(x))
                     c_x, x_sq = c_x + c_s, x_sq + sq_s
                 local_plan = jax.tree.map(lambda a: a[0], plan)
                 ax = kops.ax_aligned_x(local_plan, jnp.concatenate(parts),
@@ -204,7 +193,7 @@ class DistributedMatchingObjective:
                 for slab in slabs:
                     _, gvals, c_s, sq_s = objectives.slab_xgvals(
                         slab, lam_full, gamma, kind, iters, pallas)
-                    parts.append(gvals.reshape(-1, slab.m))
+                    parts.append(edge_space(gvals))
                     c_x, x_sq = c_x + c_s, x_sq + sq_s
                 local_plan = jax.tree.map(lambda a: a[0], plan)
                 ax = kops.ax_aligned(local_plan,
@@ -253,20 +242,20 @@ class DistributedMatchingObjective:
             def local(slabs, b, plan, lam, gamma):
                 return local_core(slabs, b, lam, gamma, plan)
 
-            fn = _shard_map(
+            fn = jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(slab_specs, b_spec, plan_specs, lam_spec, P()),
-                out_specs=out_specs,
+                out_specs=out_specs, check_vma=False,
             )
             return fn(self.lp.slabs, self.lp.b, self._plan, lam, gamma)
 
         def local(slabs, b, lam, gamma):
             return local_core(slabs, b, lam, gamma, None)
 
-        fn = _shard_map(
+        fn = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(slab_specs, b_spec, lam_spec, P()),
-            out_specs=out_specs,
+            out_specs=out_specs, check_vma=False,
         )
         return fn(self.lp.slabs, self.lp.b, lam, gamma)
 

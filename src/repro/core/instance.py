@@ -24,7 +24,7 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
-from .types import AxBucket, AxPlan, LPData, Slab
+from .types import AxBucket, AxPlan, LPData, Slab, edge_space
 
 
 class LPValidationError(ValueError):
@@ -273,7 +273,7 @@ def _flat_edges(slabs, row_slice: Optional[Tuple[int, int]] = None):
             assert d.shape[0] % n == 0, (d.shape[0], n)
             nl = d.shape[0] // n
             d, mk = d[k * nl:(k + 1) * nl], mk[k * nl:(k + 1) * nl]
-        d, mk = d.reshape(-1), mk.reshape(-1)
+        d, mk = edge_space(d), edge_space(mk)
         keep = np.nonzero(mk)[0]
         dests.append(d[keep])
         idxs.append(off + keep)
@@ -301,7 +301,7 @@ def _flat_a(slabs, row_slice: Optional[Tuple[int, int]] = None) -> np.ndarray:
             k, n = row_slice
             nl = a.shape[0] // n
             a = a[k * nl:(k + 1) * nl]
-        parts.append(a.reshape(-1, a.shape[-1]))
+        parts.append(edge_space(a))
     if not parts:
         return np.zeros((0, 1), np.float32)
     return np.concatenate(parts, axis=0)

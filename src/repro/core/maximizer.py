@@ -95,10 +95,40 @@ def _classify_chunk(health: HealthConfig, rule: UpdateRule,
     return None
 
 
+def hoist_constants(fn: Callable, *example_args):
+    """Split `fn` into `(hoisted, consts)` with `hoisted(consts, *args)`
+    equal to `fn(*args)`, where `consts` are the arrays `fn` closes over.
+
+    `jax.jit` embeds every array a traced function closes over into the
+    program as a literal.  An objective's `calculate` closes over the
+    whole instance — gigabytes at production size — so the executable
+    would carry a copy of it: minutes of compile, gigabytes of host
+    memory per program, and no persistent-cache entry (too large).  Jit
+    `hoisted` instead and pass `consts` as an argument; the arrays then
+    stay where they are, on device, and the program holds only code.
+    `example_args` fix the shapes `hoisted` is traced at.
+    """
+    closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*example_args)
+    out_tree = jax.tree.structure(out_shape)
+
+    def hoisted(consts, *args):
+        out = jax.core.eval_jaxpr(closed.jaxpr, consts, *jax.tree.leaves(args))
+        return jax.tree.unflatten(out_tree, out)
+
+    return hoisted, list(closed.consts)
+
+
+def _run_with(compiled, consts, state, gamma):
+    return compiled(state, gamma, consts)
+
+
 def _make_chunk_runner(calculate: Callable, config: SolveConfig,
                        rule: UpdateRule, length: int,
                        gamma_override: bool) -> Callable:
     """Jit one inner chunk: `length` steps as a single lax.scan.
+
+    `calculate(consts, λ, γ)` is the objective with its instance hoisted
+    out (`hoist_constants`); the runner is `run(state, γ, consts)`.
 
     `gamma_override=False`: γ follows the scheduled continuation
     `gamma_at(config, it)` inside the scan (the iteration counter is carried
@@ -116,19 +146,15 @@ def _make_chunk_runner(calculate: Callable, config: SolveConfig,
     lam/y/lam_prev/y_prev — and duplicate donation of one buffer is an
     error).
     """
-    if gamma_override:
-        def run(state, gamma):
+    def run(state, gamma, consts):
+        calc = partial(calculate, consts)
+        if gamma_override:
             gamma = jnp.asarray(gamma, jnp.float32)
-            step_fn = partial(rule.step, calculate, config,
-                              lambda st: gamma)
-            return jax.lax.scan(step_fn, state, None, length=length)
-    else:
-        step_fn = partial(rule.step, calculate, config,
-                          lambda st: gamma_at(config, st.it))
-
-        def run(state, gamma):
-            del gamma  # scheduled mode: γ comes from the carried counter
-            return jax.lax.scan(step_fn, state, None, length=length)
+            gamma_fn = lambda st: gamma  # noqa: E731
+        else:  # scheduled mode: γ comes from the carried counter
+            gamma_fn = lambda st: gamma_at(config, st.it)  # noqa: E731
+        step_fn = partial(rule.step, calc, config, gamma_fn)
+        return jax.lax.scan(step_fn, state, None, length=length)
     return jax.jit(run, donate_argnums=(0,))
 
 
@@ -185,22 +211,25 @@ class SolveEngine:
                      for leaf in jax.tree.leaves(state)))
         run = self._runners.get(key)
         if run is None:
-            fn = _make_chunk_runner(self.calculate, self.config,
-                                    self.rule, length, gamma_override)
             with tel.span("trace", chunk_len=length):
-                lowered = fn.lower(state, gamma)
+                calculate, consts = hoist_constants(
+                    self.calculate, state.lam, jnp.float32(0.0))
+                fn = _make_chunk_runner(calculate, self.config, self.rule,
+                                        length, gamma_override)
+                lowered = fn.lower(state, gamma, consts)
             with tel.span("compile", chunk_len=length):
-                run = lowered.compile()
+                compiled = lowered.compile()
             if sampler is not None:
                 # per-runner static memory estimate (memory_analysis or the
                 # hlo_cost census) — folded into the run's compiled peak and
                 # surfaced as a generic event (DESIGN.md §13)
                 from repro.obs.memory import compiled_memory_estimate
-                est = compiled_memory_estimate(run)
+                est = compiled_memory_estimate(compiled)
                 if est:
                     sampler.note_compiled(est)
                     tel.event("event", kind="compiled_memory",
                               chunk_len=length, **est)
+            run = partial(_run_with, compiled, consts)
             self._runners[key] = run
         return run
 

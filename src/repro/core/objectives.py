@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from . import projections
-from .types import AxPlan, LPData, Slab
+from .types import AxPlan, LPData, Slab, dest_gather, edge_space
 
 AX_MODES = ("scatter", "sorted", "aligned", "aligned_gvals")
 
@@ -64,7 +64,7 @@ def slab_xstar(slab: Slab, lam: jax.Array, gamma: jax.Array,
     if use_pallas:
         from repro.kernels import ops as kops
         return kops.dual_xstar(slab, lam, gamma, proj_kind, proj_iters)
-    lam_e = lam[:, slab.dest_idx]                       # (m, n, w)
+    lam_e = dest_gather(lam, slab.dest_idx)             # (m, n, w)
     atl = jnp.einsum("nwm,mnw->nw", slab.a_vals, lam_e)  # (Aᵀλ) at edges
     u = -(atl + slab.c_vals) / gamma
     return projections.project(proj_kind, u, slab.ub, slab.s, slab.mask,
@@ -99,7 +99,7 @@ def slab_xgvals(slab: Slab, lam: jax.Array, gamma: jax.Array,
             else:
                 c_x = c_x - shift * jnp.sum(x)
         return x, gvals, c_x, x_sq
-    lam_e = lam[:, slab.dest_idx]
+    lam_e = dest_gather(lam, slab.dest_idx)
     atl = jnp.einsum("nwm,mnw->nw", slab.a_vals, lam_e)
     if shift is not None:
         atl = atl + shift
@@ -136,7 +136,7 @@ def slab_xcarry(slab: Slab, lam: jax.Array, gamma: jax.Array,
             else:
                 c_x = c_x - shift * jnp.sum(x)
         return x, c_x, x_sq
-    lam_e = lam[:, slab.dest_idx]
+    lam_e = dest_gather(lam, slab.dest_idx)
     atl = jnp.einsum("nwm,mnw->nw", slab.a_vals, lam_e)
     if shift is not None:
         atl = atl + shift
@@ -163,7 +163,7 @@ def slab_contribution(slab: Slab, lam: jax.Array, gamma: jax.Array,
     """One slab's (Ax partial, cᵀx, ‖x‖²) via the destination scatter."""
     x, gvals, c_x, x_sq = slab_xgvals(slab, lam, gamma, proj_kind,
                                       proj_iters, use_pallas)
-    ax = _segment_ax(gvals.reshape(-1, slab.m), slab.dest_idx.reshape(-1),
+    ax = _segment_ax(edge_space(gvals), edge_space(slab.dest_idx),
                      num_destinations)
     return ax, c_x, x_sq
 
@@ -257,7 +257,7 @@ class MatchingObjective:
         self.sorted_scatter = ax_mode == "sorted"   # kept for introspection
         if ax_mode == "sorted":
             import numpy as np
-            dests = np.concatenate([np.asarray(s.dest_idx).reshape(-1)
+            dests = np.concatenate([edge_space(np.asarray(s.dest_idx))
                                     for s in lp.slabs])
             self._perm = jnp.asarray(np.argsort(dests, kind="stable"))
             self._sorted_dest = jnp.asarray(np.sort(dests, kind="stable"))
@@ -311,7 +311,7 @@ class MatchingObjective:
                                indices_are_sorted=True)
         ax = jnp.zeros((lp.m, J), dtype)
         for slab, part in zip(lp.slabs, parts):
-            ax = ax + _segment_ax(part, slab.dest_idx.reshape(-1), J)
+            ax = ax + _segment_ax(part, edge_space(slab.dest_idx), J)
         return ax
 
     def _forward(self, lam: jax.Array, gamma: jax.Array, shift=None,
@@ -331,11 +331,11 @@ class MatchingObjective:
             if carry:
                 x, c_s, sq_s = slab_xcarry(
                     slab, lam, gamma, kind, iters, self.use_pallas, shift)
-                parts.append(x.reshape(-1))
+                parts.append(edge_space(x))
             else:
                 x, gvals, c_s, sq_s = slab_xgvals(
                     slab, lam, gamma, kind, iters, self.use_pallas, shift)
-                parts.append(gvals.reshape(-1, slab.m))
+                parts.append(edge_space(gvals))
             c_x = c_x + c_s
             x_sq = x_sq + sq_s
             if with_xsum:

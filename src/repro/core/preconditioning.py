@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .types import LPData, Slab
+from .types import LPData, Slab, dest_gather, edge_space
 
 
 class RowScaling(NamedTuple):
@@ -32,11 +32,11 @@ def row_norms(lp: LPData) -> jax.Array:
     J = lp.num_destinations
     sq = jnp.zeros((lp.m, J), jnp.float32)
     for slab in lp.slabs:
-        flat_dest = slab.dest_idx.reshape(-1)
+        flat_dest = edge_space(slab.dest_idx)
         contrib = jax.vmap(
             lambda g: jax.ops.segment_sum(g, flat_dest, num_segments=J),
             in_axes=-1, out_axes=0,
-        )((slab.a_vals ** 2).reshape(-1, slab.m))
+        )(edge_space(slab.a_vals ** 2))
         sq = sq + contrib
     return jnp.sqrt(sq)
 
@@ -51,7 +51,7 @@ def row_normalize(lp: LPData) -> Tuple[LPData, RowScaling]:
     d = jnp.where(norms > 0, 1.0 / jnp.maximum(norms, 1e-30), 1.0)
     slabs = []
     for slab in lp.slabs:
-        d_e = d[:, slab.dest_idx]                       # (m, n, w)
+        d_e = dest_gather(d, slab.dest_idx)             # (m, n, w)
         a_new = slab.a_vals * jnp.transpose(d_e, (1, 2, 0))
         slabs.append(slab._replace(a_vals=a_new))
     return LPData(slabs=tuple(slabs), b=lp.b * d), RowScaling(d=d)

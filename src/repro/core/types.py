@@ -56,6 +56,32 @@ class Slab(NamedTuple):
         return self.a_vals.shape[2]
 
 
+def edge_space(a):
+    """Flatten one slab-shaped (n, w[, m]) array into its part of the
+    concatenated slab-edge space the AxPlan indexes (DESIGN.md §3).
+
+    The order is column-major within the slab: edge (r, q) sits at
+    q·n + r.  The TPU compiler lays an (n, w) array with a small w out
+    with n as the minor dimension, so this flatten is a plain copy there;
+    the row-major flatten is a relayout that compiles into code linear in
+    n (about a minute for n = 150k at w = 4) and stages a lane-padded
+    temporary.  Works on numpy and JAX arrays alike.
+    """
+    return a.swapaxes(0, 1).reshape(-1, *a.shape[2:])
+
+
+def dest_gather(table, dest_idx):
+    """`table[:, dest_idx]`: an (m, J) dual-shaped table read at every edge
+    of a slab, as (m, n, w).
+
+    Gathers through the transposed (w, n) index.  The TPU compiler emits
+    code linear in n for a gather whose (n, w) index has a small w (over a
+    minute at n = 1.5M, w = 8); through the transpose the same gather
+    compiles in about a second.  Same values either way.
+    """
+    return jnp.swapaxes(table[:, dest_idx.T], 1, 2)
+
+
 class AxBucket(NamedTuple):
     """One in-degree bucket of the constraint-aligned companion layout.
 
@@ -103,7 +129,8 @@ class AxPlan(NamedTuple):
     The slabs answer "which edges does source i own?"; the plan answers
     "which edges land on dual row j?".  With it, `Ax` is a *gather*:
     flatten the per-edge gradient values gvals (edge order = slab
-    concatenation order), gather each destination's incident values, and
+    concatenation order, each slab flattened by `edge_space`), gather
+    each destination's incident values, and
     masked-row-sum — no scatter, no atomics, fixed shapes.
 
     With `carry_values=True` (the default) each bucket additionally packs

@@ -39,7 +39,7 @@ from repro.core.objectives import (AX_MODES, MatchingObjective, ObjectiveAux,
                                    slab_xcarry, slab_xgvals)
 from repro.core.preconditioning import row_normalize
 from repro.core.projections import ProjectionMap
-from repro.core.types import AxPlan, LPData
+from repro.core.types import AxPlan, LPData, edge_space
 
 from .spec import Formulation, GlobalBudgetFamily
 
@@ -165,12 +165,12 @@ class ComposedObjective(MatchingObjective):
                 x, c_s, sq_s = slab_xcarry(
                     slab, lam, gamma, kind, iters, self.use_pallas,
                     self._shift_for(si, mus))
-                parts.append(x.reshape(-1))
+                parts.append(edge_space(x))
             else:
                 x, gvals, c_s, sq_s = slab_xgvals(
                     slab, lam, gamma, kind, iters, self.use_pallas,
                     self._shift_for(si, mus))
-                parts.append(gvals.reshape(-1, slab.m))
+                parts.append(edge_space(gvals))
             c_x = c_x + c_s
             x_sq = x_sq + sq_s
             for r, (w, s) in enumerate(zip(self._global_weights,

@@ -3,7 +3,7 @@
   proj.py       batched box-cut projection via τ-bisection
   dual_grad.py  fused x*(λ) + per-edge gradient values + local scalars
   ax_reduce.py  constraint-aligned gather-reduce for Ax (scatter-free)
-  ops.py        jit'd public wrappers (interpret-mode fallback off-TPU)
+  ops.py        public wrappers (Mosaic on TPU, interpret mode elsewhere)
   ref.py        pure-jnp oracles (ground truth for tests)
 """
 from . import ops, ref  # noqa: F401

@@ -16,23 +16,25 @@ the (E,) x vector alone,
     ax[row, k] = Σ_q mask[row, q] · a_dm[row, q, k] · x[edge_idx[row, q]],
 
 and the (E, m) per-edge gradient tensor never exists — the only dynamic
-per-edge array crossing HBM is x.  `a_dm` tiles block-locally through an
-ordinary BlockSpec (it is bucket-shaped, not edge-space-shaped), so the
-staged-whole operand shrinks from (E, m) gvals to the (E,) x vector: a
-m·4x (f32) / m·2x (bf16→f32-idx) smaller VMEM residency.
+per-edge array crossing HBM is x.
 
 That is exactly the gather-based formulation cuPDLP-class GPU solvers use
 to retire atomics from the transpose product — every lane does independent
 loads, the sum is a fixed-shape VPU reduction, and there is no write
 contention at all.
 
-Tiling mirrors proj.py: grid over row-blocks of one in-degree bucket; each
-kernel instance owns a (BLOCK_ROWS, width) tile of indices/mask (+ the
-matching a_dm tile in the x variant).  The staged-whole operand (gvals or
-x) uses a BlockSpec constant index map, like λ in dual_grad.py, because
-gather indices are global — fine at matching-workload sizes where it is
-the slab-edge space of one shard; production TPU deployments would chunk
-the edge space per slab and accumulate (see DESIGN.md §3).
+The gather at `edge_idx` runs in XLA in front of the kernel: Mosaic
+lowers no gather over an edge-space-sized operand ("Only 2D gather is
+supported"), and staging the whole (E,) x or (E, m) gvals into VMEM
+stops fitting long before production edge counts.  The kernel then reads
+bucket-shaped tiles only and does the FMA and the masked reduce.
+
+Tiling: a 2-D grid over (row-blocks, width-chunks) of one in-degree
+bucket.  Buckets of hub destinations are 2^17+ wide, so a row's edges
+are summed chunk by chunk into a resident output block (the width axis is
+the reduction axis, last in the grid).  Per-family tiles are
+family-major (m, rows, chunk) so the lane axis is the bucket width, and
+the output is a family-major (m, rows, 1) column block.
 
 Accumulation is always f32 (bf16 inputs included), matching dual_grad.py's
 scalar partials; products are formed in the input dtype — bit-matching the
@@ -45,23 +47,64 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .proj import _block_rows
 
+# widest width chunk one grid step reduces (lanes); wider buckets loop
+_MAX_CHUNK = 2048
 
-def _ax_reduce_kernel(g_ref, idx_ref, mask_ref, out_ref):
-    g = g_ref[...]                           # (E, m) whole edge space
-    idx = idx_ref[...]                       # (br, w) int32
-    mask = mask_ref[...] != 0                # (br, w)
-    br, w = idx.shape
-    m = g.shape[1]
-    # m is tiny (1-4 constraint families): unrolled, one gather per family.
-    cols = []
-    for k in range(m):
-        vals = jnp.take(g[:, k], idx.reshape(-1), axis=0).reshape(br, w)
-        cols.append(jnp.sum(
-            jnp.where(mask, vals.astype(jnp.float32), 0.0), axis=-1))
-    out_ref[...] = jnp.stack(cols, axis=-1)
+
+def _ax_kernel(*refs, carry_x: bool):
+    if carry_x:
+        xe_ref, a_ref, mask_ref, out_ref = refs
+        xe = xe_ref[...]                             # (br, bw)
+    else:
+        a_ref, mask_ref, out_ref = refs
+    mask = mask_ref[...] != 0                        # (br, bw)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+    # m is tiny (1-4 constraint families): unrolled, one row-sum per family
+    for k in range(a_ref.shape[0]):
+        prod = a_ref[k] * xe if carry_x else a_ref[k]
+        out_ref[k] += jnp.sum(jnp.where(mask, prod.astype(jnp.float32), 0.0),
+                              axis=-1, keepdims=True)
+
+
+def _ax_call(fam_vals, xe, mask, interpret: bool, block_rows):
+    """Masked (m, r, w) row-sum [times xe], as (r, m) float32 rows."""
+    m, r, w = fam_vals.shape
+    bw = min(w, _MAX_CHUNK)
+    w_pad = -(-w // bw) * bw
+    n_in = m + 2 if xe is not None else m + 1
+    br = block_rows or min(_block_rows(n_in * bw), max(r, 8))
+    r_pad = -(-r // br) * br
+
+    def pad(a):             # zero rows/width overhang: mask 0 drops it
+        cfg = [(0, 0)] * a.ndim
+        cfg[-2], cfg[-1] = (0, r_pad - r), (0, w_pad - w)
+        return jnp.pad(a, cfg)
+
+    tile = pl.BlockSpec((br, bw), lambda i, j: (i, j))
+    specs = [pl.BlockSpec((m, br, bw), lambda i, j: (0, i, j)), tile]
+    args = [pad(fam_vals), pad(mask.astype(jnp.int32))]
+    if xe is not None:
+        specs.insert(0, tile)
+        args.insert(0, pad(xe))
+    out = pl.pallas_call(
+        functools.partial(_ax_kernel, carry_x=xe is not None),
+        grid=(r_pad // br, w_pad // bw),
+        in_specs=specs,
+        out_specs=pl.BlockSpec((m, br, 1), lambda i, j: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, r_pad, 1), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*args)
+    return out[:, :r, 0].T
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
@@ -77,44 +120,9 @@ def ax_reduce_bucket(gvals: jax.Array, edge_idx: jax.Array, mask: jax.Array,
     E, m = gvals.shape
     if E == 0 or r == 0:
         return jnp.zeros((r, m), jnp.float32)
-    # idx + mask + one gathered tile resident at once
-    br = block_rows or min(_block_rows(3 * w), max(r, 8))
-    r_pad = -(-r // br) * br
-    if r_pad != r:
-        pad = [(0, r_pad - r), (0, 0)]
-        edge_idx = jnp.pad(edge_idx, pad)
-        mask = jnp.pad(mask, pad)
-    grid = (r_pad // br,)
-    out = pl.pallas_call(
-        _ax_reduce_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((E, m), lambda i: (0, 0)),     # gvals: whole block
-            pl.BlockSpec((br, w), lambda i: (i, 0)),
-            pl.BlockSpec((br, w), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((br, m), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r_pad, m), jnp.float32),
-        interpret=interpret,
-    )(gvals, edge_idx, mask.astype(jnp.int32))
-    return out[:r]
-
-
-def _ax_reduce_x_kernel(x_ref, a_ref, idx_ref, mask_ref, out_ref):
-    x = x_ref[...]                           # (E,) whole edge space
-    a = a_ref[...]                           # (br, w, m) block-local
-    idx = idx_ref[...]                       # (br, w) int32
-    mask = mask_ref[...] != 0                # (br, w)
-    br, w, m = a.shape
-    xe = jnp.take(x, idx.reshape(-1), axis=0).reshape(br, w)
-    # m is tiny (1-4 constraint families): unrolled, one FMA row per family.
-    # Product in input dtype (== the gvals the legacy path materializes),
-    # accumulation in f32.
-    cols = []
-    for k in range(m):
-        prod = (a[:, :, k] * xe).astype(jnp.float32)
-        cols.append(jnp.sum(jnp.where(mask, prod, 0.0), axis=-1))
-    out_ref[...] = jnp.stack(cols, axis=-1)
+    # plan indices are valid by construction: no gather bounds checks
+    g = gvals.T.at[:, edge_idx].get(mode="promise_in_bounds")   # (m, r, w)
+    return _ax_call(g, None, mask, interpret, block_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
@@ -126,33 +134,13 @@ def ax_reduce_bucket_x(x: jax.Array, a_dm: jax.Array, edge_idx: jax.Array,
     x: (E,) flattened x*(λ); a_dm: (r, w, m) static destination-major
     weights; edge_idx/mask: (r, w).  Returns (r, m) float32 partial Ax
     rows (bucket row order).  Only x is dynamic — the gathered operand is
-    m·times smaller than the gvals the legacy kernel stages.
+    m·times smaller than the gvals the legacy kernel reads.
     """
     r, w = edge_idx.shape
     (E,) = x.shape
     m = a_dm.shape[-1]
     if E == 0 or r == 0:
         return jnp.zeros((r, m), jnp.float32)
-    # idx + mask + a_dm tile + one gathered x tile resident at once
-    br = block_rows or min(_block_rows((m + 3) * w), max(r, 8))
-    r_pad = -(-r // br) * br
-    if r_pad != r:
-        pad = [(0, r_pad - r), (0, 0)]
-        edge_idx = jnp.pad(edge_idx, pad)
-        mask = jnp.pad(mask, pad)
-        a_dm = jnp.pad(a_dm, pad + [(0, 0)])
-    grid = (r_pad // br,)
-    out = pl.pallas_call(
-        _ax_reduce_x_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((E,), lambda i: (0,)),         # x: whole edge space
-            pl.BlockSpec((br, w, m), lambda i: (i, 0, 0)),
-            pl.BlockSpec((br, w), lambda i: (i, 0)),
-            pl.BlockSpec((br, w), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((br, m), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r_pad, m), jnp.float32),
-        interpret=interpret,
-    )(x, a_dm, edge_idx, mask.astype(jnp.int32))
-    return out[:r]
+    xe = x.at[edge_idx].get(mode="promise_in_bounds")           # (r, w)
+    return _ax_call(jnp.moveaxis(a_dm, -1, 0), xe, mask, interpret,
+                    block_rows)

@@ -1,13 +1,13 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels.
 
-On a TPU backend the kernels lower through Mosaic; everywhere else (this CPU
-container, unit tests) they run in interpret mode, which executes the kernel
-body in Python with identical semantics.  `repro.core.objectives` routes
-through `dual_xstar` when SolveConfig.use_pallas is set.
+On a TPU backend the kernels lower through Mosaic, always: nothing here
+picks interpret mode there.  On any other backend (this CPU container,
+unit tests) they run in interpret mode, which executes the kernel body in
+Python with identical semantics.  The choice follows the platform JAX
+runs on; callers do not make it.  `repro.core.objectives` routes through
+these wrappers when SolveConfig.use_pallas is set.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -18,54 +18,52 @@ from . import proj as _proj
 from repro.core.types import AxPlan, Slab
 
 
-def _interpret_default() -> bool:
+def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def proj_boxcut(v, ub, s, mask, iters: int = _proj.DEFAULT_ITERS,
-                interpret: bool | None = None):
-    if interpret is None:
-        interpret = _interpret_default()
-    return _proj.proj_boxcut(v, ub, s, mask, iters=iters, interpret=interpret)
+def proj_boxcut(v, ub, s, mask, iters: int = _proj.DEFAULT_ITERS):
+    return _proj.proj_boxcut(v, ub, s, mask, iters=iters,
+                             interpret=_interpret())
 
 
-def dual_grad_slab(slab: Slab, lam, gamma, iters: int = _proj.DEFAULT_ITERS,
-                   interpret: bool | None = None):
+def _kernel_slab(slab: Slab, proj_kind: str) -> Slab:
+    """The slab the boxcut kernels see for a projection kind: simplex is
+    boxcut with an unreachable per-edge bound."""
+    if proj_kind == "simplex":
+        return slab._replace(ub=jnp.full_like(slab.ub, 1e30))
+    if proj_kind not in ("boxcut", "box"):
+        raise NotImplementedError(
+            f"pallas path supports boxcut/simplex/box, got {proj_kind}")
+    return slab
+
+
+def dual_grad_slab(slab: Slab, lam, gamma, iters: int = _proj.DEFAULT_ITERS):
     """Fused x*(λ)+gvals+scalars for one slab (kernel: dual_grad.py)."""
-    if interpret is None:
-        interpret = _interpret_default()
     return _dual_grad.dual_grad_slab(
         slab.a_vals, slab.c_vals, slab.dest_idx, slab.mask, slab.ub, slab.s,
-        lam, gamma, iters=iters, interpret=interpret)
+        lam, gamma, iters=iters, interpret=_interpret())
 
 
 def dual_grad_full(slab: Slab, lam, gamma, proj_kind: str = "boxcut",
-                   iters: int = _proj.DEFAULT_ITERS,
-                   interpret: bool | None = None):
+                   iters: int = _proj.DEFAULT_ITERS):
     """Fused (x*, gvals, cᵀx, ‖x‖²) for one slab with proj-kind dispatch.
 
     Entry point used by repro.core.objectives.slab_xgvals(use_pallas=True):
     all four kernel outputs are consumed downstream — nothing recomputed.
     """
-    if proj_kind == "simplex":
-        big = jnp.full_like(slab.ub, 1e30)
-        slab = slab._replace(ub=big)
-    elif proj_kind not in ("boxcut", "box"):
-        raise NotImplementedError(
-            f"pallas path supports boxcut/simplex/box, got {proj_kind}")
-    return dual_grad_slab(slab, lam, gamma, iters=iters, interpret=interpret)
+    return dual_grad_slab(_kernel_slab(slab, proj_kind), lam, gamma,
+                          iters=iters)
 
 
 def dual_xstar(slab: Slab, lam, gamma, proj_kind: str = "boxcut",
-               iters: int = _proj.DEFAULT_ITERS,
-               interpret: bool | None = None):
+               iters: int = _proj.DEFAULT_ITERS):
     """x*(λ) for one slab via the fused kernel (boxcut/simplex kinds)."""
-    return dual_grad_full(slab, lam, gamma, proj_kind, iters, interpret)[0]
+    return dual_x_full(slab, lam, gamma, proj_kind, iters)[0]
 
 
 def dual_x_full(slab: Slab, lam, gamma, proj_kind: str = "boxcut",
-                iters: int = _proj.DEFAULT_ITERS,
-                interpret: bool | None = None):
+                iters: int = _proj.DEFAULT_ITERS):
     """Gvals-free fused (x*, cᵀx, ‖x‖²) for one slab (kernel: dual_x_slab).
 
     Entry point for the value-carrying aligned path
@@ -73,28 +71,19 @@ def dual_x_full(slab: Slab, lam, gamma, proj_kind: str = "boxcut",
     output — the (n, w, m) per-edge gradient tile — is dropped entirely;
     the x-carry Ax reduction (`ax_aligned_x`) consumes x directly.
     """
-    if proj_kind == "simplex":
-        big = jnp.full_like(slab.ub, 1e30)
-        slab = slab._replace(ub=big)
-    elif proj_kind not in ("boxcut", "box"):
-        raise NotImplementedError(
-            f"pallas path supports boxcut/simplex/box, got {proj_kind}")
-    if interpret is None:
-        interpret = _interpret_default()
+    slab = _kernel_slab(slab, proj_kind)
     return _dual_grad.dual_x_slab(
         slab.a_vals, slab.c_vals, slab.dest_idx, slab.mask, slab.ub, slab.s,
-        lam, gamma, iters=iters, interpret=interpret)
+        lam, gamma, iters=iters, interpret=_interpret())
 
 
-def ax_reduce_bucket(gvals, edge_idx, mask, interpret: bool | None = None):
-    if interpret is None:
-        interpret = _interpret_default()
+def ax_reduce_bucket(gvals, edge_idx, mask):
     return _ax_reduce.ax_reduce_bucket(gvals, edge_idx, mask,
-                                       interpret=interpret)
+                                       interpret=_interpret())
 
 
 def ax_aligned(plan: AxPlan, gvals: jax.Array, use_pallas: bool = False,
-               interpret: bool | None = None, out_dtype=None) -> jax.Array:
+               out_dtype=None) -> jax.Array:
     """Scatter-free (m, J) Ax via the destination-major companion layout.
 
     gvals: (E, m) per-edge gradient values, flattened in slab concatenation
@@ -106,15 +95,12 @@ def ax_aligned(plan: AxPlan, gvals: jax.Array, use_pallas: bool = False,
     rows = []
     for b in plan.buckets:
         if use_pallas:
-            rows.append(ax_reduce_bucket(gvals, b.edge_idx, b.mask,
-                                         interpret=interpret))
+            rows.append(ax_reduce_bucket(gvals, b.edge_idx, b.mask))
         else:  # XLA fallback: identical math, plain take+sum
-            r, w = b.edge_idx.shape
             # plan indices are valid by construction: skip gather bounds
             # checks (they constant-fold painfully over E-sized index sets)
-            g = gvals.at[b.edge_idx.reshape(-1)].get(
-                mode="promise_in_bounds")
-            g = g.reshape(r, w, gvals.shape[-1]).astype(jnp.float32)
+            g = gvals.at[b.edge_idx].get(
+                mode="promise_in_bounds").astype(jnp.float32)
             rows.append(jnp.sum(jnp.where(b.mask[..., None], g, 0.0),
                                 axis=1))
     rows = jnp.concatenate(rows, axis=0)               # (R, m) f32
@@ -123,15 +109,13 @@ def ax_aligned(plan: AxPlan, gvals: jax.Array, use_pallas: bool = False,
     return ax.astype(out_dtype or gvals.dtype)
 
 
-def ax_reduce_bucket_x(x, a_dm, edge_idx, mask, interpret: bool | None = None):
-    if interpret is None:
-        interpret = _interpret_default()
+def ax_reduce_bucket_x(x, a_dm, edge_idx, mask):
     return _ax_reduce.ax_reduce_bucket_x(x, a_dm, edge_idx, mask,
-                                         interpret=interpret)
+                                         interpret=_interpret())
 
 
 def ax_aligned_x(plan: AxPlan, x: jax.Array, use_pallas: bool = False,
-                 interpret: bool | None = None, out_dtype=None) -> jax.Array:
+                 out_dtype=None) -> jax.Array:
     """Value-carrying scatter-free (m, J) Ax: the x-only hot path.
 
     x: (E,) x*(λ) values, flattened in slab concatenation order (the
@@ -151,12 +135,9 @@ def ax_aligned_x(plan: AxPlan, x: jax.Array, use_pallas: bool = False,
                 "ax_aligned_x needs a value-carrying plan; rebuild with "
                 "build_ax_plan(lp, carry_values=True)")
         if use_pallas:
-            rows.append(ax_reduce_bucket_x(x, b.a_dm, b.edge_idx, b.mask,
-                                           interpret=interpret))
+            rows.append(ax_reduce_bucket_x(x, b.a_dm, b.edge_idx, b.mask))
         else:  # XLA fallback: identical math, plain take+multiply+sum
-            r, w = b.edge_idx.shape
-            xe = x.at[b.edge_idx.reshape(-1)].get(
-                mode="promise_in_bounds").reshape(r, w)
+            xe = x.at[b.edge_idx].get(mode="promise_in_bounds")
             prod = (b.a_dm * xe[..., None]).astype(jnp.float32)
             rows.append(jnp.sum(jnp.where(b.mask[..., None], prod, 0.0),
                                 axis=1))

@@ -7,8 +7,10 @@ branch-free, VPU-vectorized over (rows × width) tiles, no data-dependent
 control flow, fixed iteration count — exactly what Mosaic compiles well.
 
 Tiling: grid over row-blocks; each kernel instance owns a
-(BLOCK_ROWS, width) tile of v/ub/mask and a (BLOCK_ROWS,) slice of s, all
-VMEM-resident.  The inner fori_loop does `iters` rounds of
+(BLOCK_ROWS, width) tile of v/ub/mask and a (BLOCK_ROWS, 1) column of s,
+all VMEM-resident.  Every per-row quantity (s, τ, the bracket) stays a
+(rows, 1) column: Mosaic lays a rank-1 block out differently from XLA, so
+the kernels never hand it one.  The inner fori_loop does `iters` rounds of
 f(τ) = Σ clip(v−τ, 0, ub) per row (one VPU reduction per round).
 Width is the slab's power-of-two bucket width — already lane-aligned for
 buckets >= 128; small buckets underfill lanes but are cheap in absolute terms.
@@ -46,32 +48,48 @@ def _block_rows(width: int, dtype_bytes: int = 4,
     return 1 << (rows.bit_length() - 1)
 
 
-def _proj_kernel(v_ref, ub_ref, s_ref, mask_ref, x_ref, *, iters: int):
-    v = v_ref[...]
-    ub = ub_ref[...]
-    s = s_ref[...]
-    mask = mask_ref[...] != 0
+def pad_rows(a: jax.Array, n_pad: int, fill=0) -> jax.Array:
+    """Pad the leading (row) axis of `a` to `n_pad` rows with `fill`."""
+    extra = n_pad - a.shape[0]
+    if extra == 0:
+        return a
+    return jnp.pad(a, [(0, extra)] + [(0, 0)] * (a.ndim - 1),
+                   constant_values=fill)
+
+
+def bisect_project(v, ub, s, mask, iters: int):
+    """Row-wise box-cut projection of a (rows, w) tile; `s` is (rows, 1).
+
+    The kernel-side twin of `ref.boxcut_bisect_ref` (same operations in
+    the same order, per-row values kept as (rows, 1) columns), shared by
+    every kernel of this package that projects.
+    """
     neg = jnp.asarray(-1e30, v.dtype)
     v = jnp.where(mask, v, neg)
-
-    x0 = jnp.clip(v, 0.0, ub)
-    f0 = jnp.sum(jnp.where(mask, x0, 0.0), axis=-1)
+    f0 = jnp.sum(jnp.where(mask, jnp.clip(v, 0.0, ub), 0.0), axis=-1,
+                 keepdims=True)
     need = f0 > s
-    hi = jnp.max(v, axis=-1)
+    hi = jnp.max(v, axis=-1, keepdims=True)
     lo = jnp.minimum(jnp.zeros_like(hi), hi)
 
     def body(_, carry):
         lo, hi = carry
         mid = 0.5 * (lo + hi)
-        xm = jnp.clip(v - mid[:, None], 0.0, ub)
-        f = jnp.sum(jnp.where(mask, xm, 0.0), axis=-1)
+        xm = jnp.clip(v - mid, 0.0, ub)
+        f = jnp.sum(jnp.where(mask, xm, 0.0), axis=-1, keepdims=True)
         big = f > s
         return jnp.where(big, mid, lo), jnp.where(big, hi, mid)
 
     lo, hi = jax.lax.fori_loop(0, iters, body, (lo, hi))
     tau = jnp.where(need, 0.5 * (lo + hi), 0.0)
-    x = jnp.clip(v - tau[:, None], 0.0, ub)
-    x_ref[...] = jnp.where(mask, x, 0.0).astype(x_ref.dtype)
+    x = jnp.clip(v - tau, 0.0, ub)
+    return jnp.where(mask, x, 0.0)
+
+
+def _proj_kernel(v_ref, ub_ref, s_ref, mask_ref, x_ref, *, iters: int):
+    x = bisect_project(v_ref[...], ub_ref[...], s_ref[...],
+                       mask_ref[...] != 0, iters)
+    x_ref[...] = x.astype(x_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("iters", "interpret", "block_rows"))
@@ -86,23 +104,16 @@ def proj_boxcut(v: jax.Array, ub: jax.Array, s: jax.Array, mask: jax.Array,
     n, w = v.shape
     br = block_rows or _block_rows(w, n=n)
     n_pad = -(-n // br) * br
-    if n_pad != n:
-        pad = lambda a, fill: jnp.pad(a, [(0, n_pad - n)] + [(0, 0)] * (a.ndim - 1),
-                                      constant_values=fill)
-        v, ub, s = pad(v, 0), pad(ub, 0), pad(s, 1.0)
-        mask = pad(mask, False)
-    grid = (n_pad // br,)
+    v, ub = pad_rows(v, n_pad), pad_rows(ub, n_pad)
+    s = pad_rows(s, n_pad, 1.0).reshape(n_pad, 1)
+    mask = pad_rows(mask.astype(jnp.int32), n_pad)
+    tile = pl.BlockSpec((br, w), lambda i: (i, 0))
     out = pl.pallas_call(
         functools.partial(_proj_kernel, iters=iters),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, w), lambda i: (i, 0)),
-            pl.BlockSpec((br, w), lambda i: (i, 0)),
-            pl.BlockSpec((br,), lambda i: (i,)),
-            pl.BlockSpec((br, w), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((br, w), lambda i: (i, 0)),
+        grid=(n_pad // br,),
+        in_specs=[tile, tile, pl.BlockSpec((br, 1), lambda i: (i, 0)), tile],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((n_pad, w), v.dtype),
         interpret=interpret,
-    )(v, ub, s, mask.astype(jnp.int32))
+    )(v, ub, s, mask)
     return out[:n]

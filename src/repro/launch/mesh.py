@@ -11,32 +11,21 @@ Mesh shapes (assignment spec):
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types on Mesh
-    from jax.sharding import AxisType
-except ImportError:  # older jax: Auto is the only (implicit) behavior
-    AxisType = None
-
-
-def _axis_type_kwargs(num_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * num_axes}
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
               devices=None) -> Mesh:
     """jax.make_mesh with explicit Auto axis types (SPMD propagation)."""
+    axis_types = (AxisType.Auto,) * len(axes)
     if devices is not None:
-        import numpy as np
         return Mesh(np.asarray(devices).reshape(tuple(shape)), tuple(axes),
-                    **_axis_type_kwargs(len(axes)))
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                    axis_types=axis_types)
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=axis_types)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -38,6 +38,7 @@ from repro.core import (HealthConfig, InstanceSpec, LPValidationError,
 from repro.core.types import StopReason
 from repro.core.distributed import solve_distributed
 from repro.checkpoint.manager import CheckpointManager
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.obs import JsonlSink, LEVELS, ProfilerHook, Telemetry
 from repro.obs.memory import MemorySampler
@@ -153,25 +154,27 @@ def apply_warm_start_policy(cfg: SolveConfig, meta: dict,
 def attach_byte_census(tel: Telemetry, obj, lam, gamma: float) -> None:
     """Attach an hlo_cost census of one dual value+grad evaluation to the
     run manifest: flops / bytes / collective bytes per iteration at the
-    served problem size (DESIGN.md §11).  Best-effort — a lowering the
-    analyzer cannot parse downgrades to a warning, never a failed solve.
+    served problem size (DESIGN.md §11).  A lowering the analyzer cannot
+    parse fails the run: a census that is silently missing reads as one
+    that was never asked for.
     """
+    from repro.core.maximizer import hoist_constants
     from repro.launch import hlo_cost
-    try:
-        txt = (jax.jit(obj.calculate)
-               .lower(jnp.asarray(lam), jnp.float32(gamma))
-               .compile().as_text())
-        cost = hlo_cost.analyze(txt)
-        tel.manifest(hlo_cost={
-            "flops_per_iteration": cost["flops_per_device"],
-            "bytes_per_iteration": cost["bytes_per_device"],
-            "collective_bytes_per_iteration":
-                cost["collective_bytes_per_device"]})
-    except Exception as e:
-        tel.warning(f"hlo_cost census skipped: {type(e).__name__}: {e}")
+    lam, gamma = jnp.asarray(lam), jnp.float32(gamma)
+    calculate, consts = hoist_constants(obj.calculate, lam, gamma)
+    txt = (jax.jit(calculate).lower(consts, lam, gamma)
+           .compile().as_text())
+    cost = hlo_cost.analyze(txt)
+    tel.manifest(hlo_cost={
+        "flops_per_iteration": cost["flops_per_device"],
+        "bytes_per_iteration": cost["bytes_per_device"],
+        "collective_bytes_per_iteration":
+            cost["collective_bytes_per_device"]})
 
 
-def main():
+def main(argv=None) -> dict:
+    """Run the launcher on `argv` (default: the command line) and return
+    the result object that `--json` prints."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--sources", type=int, default=100_000)
     ap.add_argument("--destinations", type=int, default=1_000)
@@ -286,7 +289,8 @@ def main():
                          "`memory` event) when this process's RSS crosses "
                          "MB MiB — the measurement hook for the "
                          "larger-than-RSS out-of-core gate")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    args.argv = list(sys.argv[1:] if argv is None else argv)
     if args.resume and not args.checkpoint_dir:
         ap.error("--resume requires --checkpoint-dir")
     if args.lambda_sharded and args.formulation != "matching":
@@ -322,6 +326,7 @@ def main():
     if args.metrics_port is not None:
         exporter = MetricsExporter(registry, args.metrics_port)
         tel.info(f"serving /metrics on {exporter.url}")
+    tel.info(f"compilation cache: {enable_compile_cache()}")
     try:
         result = _run(args, tel, profiler, sampler=sampler,
                       registry=registry)
@@ -331,6 +336,7 @@ def main():
         if exporter is not None:
             exporter.close()
         tel.close()
+    return result
 
 
 def _run(args, tel: Telemetry, profiler, sampler=None,
@@ -386,7 +392,7 @@ def _run(args, tel: Telemetry, profiler, sampler=None,
         iterations_cap=args.iterations,
         check_every=(criteria.check_every if criteria else None),
         config=dataclasses.asdict(cfg),
-        argv=sys.argv[1:])
+        argv=args.argv)
 
     # -- fault tolerance (DESIGN.md §9) ---------------------------------
     health = (HealthConfig(max_retries=args.max_retries)
@@ -634,6 +640,8 @@ def _run(args, tel: Telemetry, profiler, sampler=None,
                                           sampler=sampler)
             tel.info(primal_sub.format_certificate(cert))
             result["certificate_valid"] = bool(cert.valid)
+            result["certificate_gap"] = cert.gap
+            result["certificate_gap_rel"] = cert.gap_rel
 
     if sampler is not None:
         # fold the export/certify sampling into the run-level watermarks

@@ -29,12 +29,15 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import threading
 import weakref
 from typing import Iterator, List, Optional, Sequence
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+
+from repro.core.maximizer import hoist_constants
 
 # one jitted row-subset recovery fn per (objective, slab) — shared by the
 # streaming extractor AND the allocation server (primal.server), so a query
@@ -47,7 +50,7 @@ def primal_rows_fn(obj, slab_index: int):
     """The cached jitted `(λ, γ, rows) -> x` row-subset recovery for one
     slab of `obj` (compiled once per distinct `rows` length).
 
-    The jitted closure holds only a *weakref* to the objective — a strong
+    The function holds only a *weakref* to the objective — a strong
     reference would chain value→key inside the WeakKeyDictionary and make
     every entry immortal (a replaced objective's slabs, plan, and compiled
     executables would leak across `warm_resolve` instance updates).
@@ -58,11 +61,37 @@ def primal_rows_fn(obj, slab_index: int):
         _JIT_CACHE[obj] = per_obj
     fn = per_obj.get(slab_index)
     if fn is None:
-        ref = weakref.ref(obj)
-        fn = jax.jit(lambda lam, gamma, rows, _si=slab_index:
-                     ref().primal_rows(lam, gamma, _si, rows))
+        fn = _RowsFn(weakref.ref(obj), slab_index)
         per_obj[slab_index] = fn
     return fn
+
+
+class _RowsFn:
+    """`(λ, γ, rows) -> obj.primal_rows(λ, γ, slab_index, rows)`, jitted
+    per `rows` length with the slab data passed in as an argument rather
+    than compiled into the program (`core.maximizer.hoist_constants`)."""
+
+    def __init__(self, obj_ref, slab_index: int):
+        self._obj_ref = obj_ref
+        self._slab_index = slab_index
+        self._by_shape = {}
+        self._lock = threading.Lock()   # server threads share this fn
+
+    def __call__(self, lam, gamma, rows):
+        lam = jnp.asarray(lam)
+        gamma = jnp.asarray(gamma, jnp.float32)
+        rows = jnp.asarray(rows)
+        key = (lam.shape, lam.dtype, rows.shape, rows.dtype)
+        with self._lock:
+            entry = self._by_shape.get(key)
+            if entry is None:
+                obj, si = self._obj_ref(), self._slab_index
+                hoisted, consts = hoist_constants(
+                    lambda l, g, r: obj.primal_rows(l, g, si, r),
+                    lam, gamma, rows)
+                entry = self._by_shape[key] = (jax.jit(hoisted), consts)
+        fn, consts = entry
+        return fn(consts, lam, gamma, rows)
 
 
 @dataclasses.dataclass(frozen=True)

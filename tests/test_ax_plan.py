@@ -21,6 +21,7 @@ from repro.core import (GlobalCountObjective, InstanceSpec, MatchingObjective,
                         Maximizer, SolveConfig, build_ax_plan,
                         build_sharded_ax_plan, generate, precondition)
 from repro.core.distributed import pad_for_sharding, solve_distributed
+from repro.core.types import edge_space
 from repro.kernels import ops as kops, ref as kref
 from repro.launch.mesh import make_mesh
 
@@ -29,8 +30,8 @@ def _edge_map(slabs):
     """{destination: sorted flat edge positions} ground truth from slabs."""
     out, off = {}, 0
     for s in slabs:
-        d = np.asarray(s.dest_idx).reshape(-1)
-        mk = np.asarray(s.mask).reshape(-1).astype(bool)
+        d = edge_space(np.asarray(s.dest_idx))
+        mk = edge_space(np.asarray(s.mask)).astype(bool)
         for pos in np.nonzero(mk)[0]:
             out.setdefault(int(d[pos]), []).append(off + int(pos))
         off += d.size
@@ -80,7 +81,7 @@ class TestPlanPacking:
     def test_a_dm_packing_parity(self, lp):
         """a_dm[r, q] == a_flat[edge_idx[r, q]] on real slots, 0 on padding."""
         plan = build_ax_plan(lp)
-        a_flat = np.concatenate([np.asarray(s.a_vals).reshape(-1, lp.m)
+        a_flat = np.concatenate([edge_space(np.asarray(s.a_vals))
                                  for s in lp.slabs])
         for b in plan.buckets:
             assert b.a_dm.shape == (*b.edge_idx.shape, lp.m)
@@ -100,8 +101,8 @@ class TestPlanPacking:
             locals_ = []
             for s in lp_pad.slabs:
                 nl = s.n // n_shards
-                locals_.append(np.asarray(s.a_vals)[k * nl:(k + 1) * nl]
-                               .reshape(-1, lp.m))
+                locals_.append(edge_space(
+                    np.asarray(s.a_vals)[k * nl:(k + 1) * nl]))
             a_flat = np.concatenate(locals_)
             for b in plan.buckets:
                 want = np.where(np.asarray(b.mask[k])[..., None],
@@ -153,10 +154,10 @@ class TestAlignedReduction:
         gv = jnp.asarray(rng.normal(size=(E, lp.m)).astype(np.float32),
                          dtype=dtype)
         # zero padded-edge values, as real gvals are (a_vals=0 on padding)
-        mask = jnp.concatenate([jnp.asarray(s.mask).reshape(-1)
+        mask = jnp.concatenate([edge_space(jnp.asarray(s.mask))
                                 for s in lp.slabs])
         gv = jnp.where(mask[:, None], gv, 0)
-        dests = jnp.concatenate([s.dest_idx.reshape(-1) for s in lp.slabs])
+        dests = jnp.concatenate([edge_space(s.dest_idx) for s in lp.slabs])
         ref = jax.vmap(lambda g: jax.ops.segment_sum(
             g.astype(jnp.float32), dests,
             num_segments=lp.num_destinations),
@@ -207,7 +208,7 @@ class TestAlignedReduction:
         rng = np.random.default_rng(3)
         x = jnp.asarray(rng.normal(size=(E,)).astype(np.float32),
                         dtype=dtype)
-        a_flat = jnp.concatenate([s.a_vals.reshape(-1, lp.m)
+        a_flat = jnp.concatenate([edge_space(s.a_vals)
                                   for s in lp.slabs]).astype(dtype)
         gv = a_flat * x[:, None]
         want = kops.ax_aligned(plan, gv, out_dtype=jnp.float32)

@@ -122,3 +122,56 @@ class TestAdaptiveRestart:
         new_state, stats = self._step(state, [0.1] * 4)
         assert float(stats.step) == pytest.approx(self.CFG.initial_step,
                                                   rel=1e-6)
+
+
+class TestInstanceAsArgument:
+    """The compiled programs take the instance as an argument: an array a
+    jitted function closes over is otherwise compiled into the program as
+    a literal, a copy of the whole instance per executable."""
+
+    @pytest.fixture(scope="class")
+    def obj(self):
+        import jax
+        from repro.core import (InstanceSpec, MatchingObjective, generate,
+                                precondition)
+        lp = generate(InstanceSpec(num_sources=3000, num_destinations=100,
+                                   avg_nnz_per_row=8, seed=0))
+        lp = precondition(jax.tree.map(jnp.asarray, lp), row_norm=True)[0]
+        return MatchingObjective(lp, ax_mode="aligned")
+
+    @staticmethod
+    def _value_bytes(slabs):
+        """Bytes of the slab arrays every evaluation reads."""
+        return sum(s.a_vals.nbytes + s.c_vals.nbytes for s in slabs)
+
+    def test_hoisted_equals_closure(self, obj):
+        import jax
+        from repro.core.maximizer import hoist_constants
+        lam = jnp.full(obj.dual_shape, 0.5, jnp.float32)
+        gamma = jnp.float32(0.05)
+        calc, consts = hoist_constants(obj.calculate, lam, gamma)
+        got = jax.jit(calc)(consts, lam, gamma)
+        want = jax.jit(obj.calculate)(lam, gamma)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-6)
+
+    def test_chunk_runner_takes_instance_as_argument(self, obj):
+        from repro.core.maximizer import SolveEngine
+        eng = SolveEngine(obj.calculate, SolveConfig(iterations=5))
+        state = eng.rule.init_state(jnp.zeros(obj.dual_shape), eng.config)
+        run = eng._runner(5, False, state, jnp.float32(0.01))
+        mem = run.args[0].memory_analysis()
+        assert mem.argument_size_in_bytes >= self._value_bytes(obj.lp.slabs)
+
+    def test_primal_rows_fn_takes_slab_as_argument(self, obj):
+        from repro.primal import primal_rows_fn
+        fn = primal_rows_fn(obj, 0)
+        lam = jnp.zeros(obj.dual_shape, jnp.float32)
+        rows = jnp.arange(4, dtype=jnp.int32)
+        fn(lam, 0.05, rows)
+        (jitted, consts), = fn._by_shape.values()
+        mem = jitted.lower(consts, lam, jnp.float32(0.05),
+                           rows).compile().memory_analysis()
+        assert (mem.argument_size_in_bytes
+                >= self._value_bytes(obj.lp.slabs[:1]))
