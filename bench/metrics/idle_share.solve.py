@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device
+(moves `solve_s`)."""
+from bench.lib.readers import idle_share_pct
+
+
+def read(r):
+    return idle_share_pct(r)
