@@ -27,6 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.obs import spanned
+
 from . import objectives
 from .maximizer import _infeas_scale, maximize
 from .types import (AxPlan, HealthConfig, LPData, Slab, SolveConfig,
@@ -59,6 +61,7 @@ def pad_for_sharding(lp: LPData, num_shards: int) -> LPData:
     )
 
 
+@spanned("build.place")
 def place_lp(lp: LPData, mesh: Mesh, source_axes: Tuple[str, ...],
              lambda_axis: Optional[str] = None) -> LPData:
     """device_put the LP with slab rows sharded over the source axes."""
@@ -182,9 +185,11 @@ class DistributedMatchingObjective:
                     parts.append(edge_space(x))
                     c_x, x_sq = c_x + c_s, x_sq + sq_s
                 local_plan = jax.tree.map(lambda a: a[0], plan)
-                ax = kops.ax_aligned_x(local_plan, jnp.concatenate(parts),
-                                       use_pallas=pallas,
-                                       out_dtype=lam_full.dtype)
+                with jax.named_scope(objectives.AX):
+                    ax = kops.ax_aligned_x(local_plan,
+                                           jnp.concatenate(parts),
+                                           use_pallas=pallas,
+                                           out_dtype=lam_full.dtype)
             elif ax_mode == "aligned_gvals":
                 # shard-local scatter-free reduce over materialized gvals
                 from repro.kernels import ops as kops
@@ -196,10 +201,11 @@ class DistributedMatchingObjective:
                     parts.append(edge_space(gvals))
                     c_x, x_sq = c_x + c_s, x_sq + sq_s
                 local_plan = jax.tree.map(lambda a: a[0], plan)
-                ax = kops.ax_aligned(local_plan,
-                                     jnp.concatenate(parts, axis=0),
-                                     use_pallas=pallas,
-                                     out_dtype=lam_full.dtype)
+                with jax.named_scope(objectives.AX):
+                    ax = kops.ax_aligned(local_plan,
+                                         jnp.concatenate(parts, axis=0),
+                                         use_pallas=pallas,
+                                         out_dtype=lam_full.dtype)
             else:
                 ax = jnp.zeros((lam_full.shape[0], J), lam_full.dtype)
                 c_x = jnp.zeros((), lam_full.dtype)
@@ -208,26 +214,28 @@ class DistributedMatchingObjective:
                     ax_s, c_s, sq_s = objectives.slab_contribution(
                         slab, lam_full, gamma, J, kind, iters, pallas)
                     ax, c_x, x_sq = ax + ax_s, c_x + c_s, x_sq + sq_s
-            # the ONE collective round of the paper's iteration:
-            c_x = jax.lax.psum(c_x, source_axes)
-            x_sq = jax.lax.psum(x_sq, source_axes)
-            if lam_axis is not None:
-                # sum row contributions across lam_axis while scattering J
-                ax = jax.lax.psum_scatter(
-                    ax, lam_axis, scatter_dimension=1, tiled=True)
-                if other_axes:
-                    ax = jax.lax.psum(ax, other_axes)
-            else:
-                ax = jax.lax.psum(ax, source_axes)
-            grad = ax - b
-            g_local = jnp.vdot(lam, grad)
-            if lam_axis is not None:
-                g_local = jax.lax.psum(g_local, lam_axis)
-            g = c_x + 0.5 * gamma * x_sq + g_local
-            sq_pos = jnp.sum(jnp.maximum(grad, 0.0) ** 2)
-            if lam_axis is not None:
-                sq_pos = jax.lax.psum(sq_pos, lam_axis)
-            infeas = jnp.sqrt(sq_pos)
+            with jax.named_scope(objectives.COLLECTIVE):
+                # the ONE collective round of the paper's iteration:
+                c_x = jax.lax.psum(c_x, source_axes)
+                x_sq = jax.lax.psum(x_sq, source_axes)
+                if lam_axis is not None:
+                    # sum row contributions across lam_axis while
+                    # scattering J
+                    ax = jax.lax.psum_scatter(
+                        ax, lam_axis, scatter_dimension=1, tiled=True)
+                    if other_axes:
+                        ax = jax.lax.psum(ax, other_axes)
+                else:
+                    ax = jax.lax.psum(ax, source_axes)
+                grad = ax - b
+                g_local = jnp.vdot(lam, grad)
+                if lam_axis is not None:
+                    g_local = jax.lax.psum(g_local, lam_axis)
+                g = c_x + 0.5 * gamma * x_sq + g_local
+                sq_pos = jnp.sum(jnp.maximum(grad, 0.0) ** 2)
+                if lam_axis is not None:
+                    sq_pos = jax.lax.psum(sq_pos, lam_axis)
+                infeas = jnp.sqrt(sq_pos)
             aux = objectives.ObjectiveAux(primal_obj=c_x, x_sq=x_sq, ax=ax,
                                           infeas=infeas)
             return g, grad, aux

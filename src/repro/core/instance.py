@@ -24,6 +24,8 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs import spanned
+
 from .types import AxBucket, AxPlan, LPData, Slab, edge_space
 
 
@@ -227,6 +229,7 @@ def _rhs(spec: InstanceSpec, src, dst, a) -> np.ndarray:
     return b
 
 
+@spanned("build.pack")
 def pack_slabs(src, dst, value, a, spec: InstanceSpec) -> LPData:
     """Bucket sources by ⌈log2 degree⌉ and pack padded slabs (DESIGN.md §2)."""
     I, J, m = spec.num_sources, spec.num_destinations, spec.num_families
@@ -343,6 +346,7 @@ def _pack_ax_rows(dest, idx, J: int, widths: np.ndarray,
     return buckets, row_pos
 
 
+@spanned("build.ax_plan")
 def build_ax_plan(lp: LPData, min_width: int = 4,
                   carry_values: bool = True) -> AxPlan:
     """Pack the destination-major companion layout (DESIGN.md §3), host-side,
@@ -370,6 +374,7 @@ def build_ax_plan(lp: LPData, min_width: int = 4,
         inv_perm=row_pos.astype(np.int32))
 
 
+@spanned("build.ax_plan")
 def build_sharded_ax_plan(lp: LPData, num_shards: int, min_width: int = 4,
                           carry_values: bool = True) -> AxPlan:
     """Per-shard AxPlans over the block row-partition of an (already padded)

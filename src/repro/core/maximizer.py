@@ -44,7 +44,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.obs import Telemetry
+from repro.obs import Telemetry, note_op_scopes
 from .types import (ConvergenceCheck, HealthConfig, HealthRecord, IterStats,
                     SolveConfig, SolveResult, SolveState, StopReason,
                     StoppingCriteria)
@@ -128,13 +128,23 @@ def _make_chunk_runner(calculate: Callable, config: SolveConfig,
     """Jit one inner chunk: `length` steps as a single lax.scan.
 
     `calculate(consts, λ, γ)` is the objective with its instance hoisted
-    out (`hoist_constants`); the runner is `run(state, γ, consts)`.
+    out (`hoist_constants`); the runner is `solve_chunk(state, γ, consts)`.
 
     `gamma_override=False`: γ follows the scheduled continuation
     `gamma_at(config, it)` inside the scan (the iteration counter is carried
     in the state, so chunking does not perturb the schedule).
     `gamma_override=True`: γ is a traced scalar argument, constant within the
     chunk — the host controller drives it (adaptive stall-decay).
+
+    Op-name scopes (DESIGN.md §11): the rule's step runs under `update`
+    and the objective under `sweep`, whose stages carry their own
+    `sweep.*` scopes; an op belongs to its innermost scope, so `update`
+    holds exactly the step's work outside `calculate`.  Scopes are
+    trace-time metadata: numerics and fusion are unchanged.  JAX's
+    persistent compilation cache keys a program with that metadata
+    stripped, so it may hand back an executable compiled under other
+    scopes; the runner's name, part of the key, tells the scoped program
+    (`solve_chunk`) apart from the unscoped one that preceded it.
 
     The incoming SolveState is *donated*: XLA aliases the carry buffers
     (λ, momentum, Lipschitz bookkeeping) into the outgoing state instead of
@@ -146,16 +156,22 @@ def _make_chunk_runner(calculate: Callable, config: SolveConfig,
     lam/y/lam_prev/y_prev — and duplicate donation of one buffer is an
     error).
     """
-    def run(state, gamma, consts):
-        calc = partial(calculate, consts)
+    def solve_chunk(state, gamma, consts):
+        def calc(lam, g):
+            with jax.named_scope("sweep"):
+                return calculate(consts, lam, g)
+
         if gamma_override:
             gamma = jnp.asarray(gamma, jnp.float32)
             gamma_fn = lambda st: gamma  # noqa: E731
         else:  # scheduled mode: γ comes from the carried counter
             gamma_fn = lambda st: gamma_at(config, st.it)  # noqa: E731
-        step_fn = partial(rule.step, calc, config, gamma_fn)
+
+        def step_fn(state, xs):
+            with jax.named_scope("update"):
+                return rule.step(calc, config, gamma_fn, state, xs)
         return jax.lax.scan(step_fn, state, None, length=length)
-    return jax.jit(run, donate_argnums=(0,))
+    return jax.jit(solve_chunk, donate_argnums=(0,))
 
 
 class SolveEngine:
@@ -219,16 +235,15 @@ class SolveEngine:
                 lowered = fn.lower(state, gamma, consts)
             with tel.span("compile", chunk_len=length):
                 compiled = lowered.compile()
+            note_op_scopes(compiled)
             if sampler is not None:
                 # per-runner static memory estimate (memory_analysis or the
-                # hlo_cost census) — folded into the run's compiled peak and
-                # surfaced as a generic event (DESIGN.md §13)
+                # hlo_cost census), folded into the run's compiled peak
+                # (DESIGN.md §13)
                 from repro.obs.memory import compiled_memory_estimate
                 est = compiled_memory_estimate(compiled)
                 if est:
                     sampler.note_compiled(est)
-                    tel.event("event", kind="compiled_memory",
-                              chunk_len=length, **est)
             run = partial(_run_with, compiled, consts)
             self._runners[key] = run
         return run
@@ -269,13 +284,13 @@ class SolveEngine:
                          adaptive-continuation controller variables.
 
           telemetry      a `repro.obs.Telemetry`; the engine emits
-                         solve_start/solve_end brackets, trace/compile
-                         spans per runner build, execute/host spans per
-                         chunk, `check`/`gamma`/`health`/`checkpoint`
+                         solve_start/solve_end brackets, the phase spans
+                         below, `check`/`gamma`/`health`/`checkpoint`
                          events at the existing seams, and chunk/
                          iteration counters.  Defaults to the disabled
                          no-op — the untelemetered trajectory is bitwise
-                         identical (tests/test_telemetry.py);
+                         identical (tests/test_telemetry.py), and its
+                         spans still annotate a profiler trace;
           profiler       a `repro.obs.ProfilerHook` tracing a window of
                          chunks via jax.profiler (stopped in a finally
                          block, so an aborted solve still flushes);
@@ -290,12 +305,53 @@ class SolveEngine:
                          trajectory is bitwise identical
                          (tests/test_memory_obs.py).
 
+        Phase spans (DESIGN.md §11): `solve` covers the call; inside it
+        `start` (state init, the first preempt poll), then per chunk
+        `execute` (runner lookup with its `trace`/`compile` spans on a
+        build, the γ upload, the dispatch), `host` (the read-back of the
+        chunk's four scalars) and `control` (everything after it up to the
+        next chunk: health guard, stopping rules, γ controller, hooks, the
+        next preempt poll), and `finish` (the stats and the result).  They
+        tile the loop: no engine work runs outside them.
+
         Any of health/checkpoint_fn/preempt_fn/initial_state forces the
         chunked path; with none of them and no criteria the fixed-length
         single-scan fast path is bit-identical to the legacy engine.
         """
-        config = self.config
         tel = telemetry if telemetry is not None else Telemetry.disabled()
+        with tel.span("solve"):
+            return self._solve(
+                tel, lam0, criteria, diagnostics_fn, infeas_scale, health,
+                checkpoint_fn, preempt_fn, initial_state, resume_meta,
+                profiler, sampler)
+
+    def _start_state(self, tel, lam0, initial_state, total, chunked,
+                     adaptive) -> SolveState:
+        """The solve's private initial state; emits `solve_start`.
+
+        The chunk runners donate the state argument (buffer reuse across
+        chunks — no double-buffered dual state).  The fresh initial state
+        aliases lam0 into four leaves, and the caller may hold lam0 (warm
+        starts) or a restored checkpoint: copy every leaf so donation
+        never invalidates a caller buffer nor donates one buffer twice.
+        """
+        config = self.config
+        if initial_state is not None:
+            state = _copy_state(initial_state)
+        else:
+            state = _copy_state(self.rule.init_state(lam0, config))
+        tel.event("solve_start", algorithm=self.algorithm,
+                  iterations_cap=total, chunked=chunked,
+                  start_it=(int(jax.device_get(initial_state.it))
+                            if initial_state is not None else 0),
+                  gamma=config.gamma, gamma_init=config.gamma_init,
+                  adaptive_continuation=adaptive)
+        return state
+
+    def _solve(self, tel, lam0, criteria, diagnostics_fn, infeas_scale,
+               health, checkpoint_fn, preempt_fn, initial_state,
+               resume_meta, profiler, sampler) -> SolveResult:
+        config = self.config
         total = config.iterations
         if criteria is not None and criteria.max_iterations is not None:
             total = criteria.max_iterations
@@ -308,109 +364,108 @@ class SolveEngine:
                    (total > 0 and
                     (adaptive
                      or (criteria is not None and criteria.needs_checks))))
-        # The chunk runners donate the state argument (buffer reuse across
-        # chunks — no double-buffered dual state).  The fresh initial state
-        # aliases lam0 into four leaves, and the caller may hold lam0 (warm
-        # starts) or a restored checkpoint: copy every leaf so donation
-        # never invalidates a caller buffer nor donates one buffer twice.
-        if initial_state is not None:
-            state = _copy_state(initial_state)
-        else:
-            state = _copy_state(self.rule.init_state(lam0, config))
-        gamma_dev = jnp.asarray(config.gamma, jnp.float32)
-        tel.event("solve_start", algorithm=self.algorithm,
-                  iterations_cap=total, chunked=chunked,
-                  start_it=(int(jax.device_get(initial_state.it))
-                            if initial_state is not None else 0),
-                  gamma=config.gamma, gamma_init=config.gamma_init,
-                  adaptive_continuation=adaptive)
-
         if not chunked:
             # Fixed-length path: ONE scan of the full count — bit-identical
             # to the legacy engine, no host round-trips.
+            with tel.span("start"):
+                state = self._start_state(tel, lam0, initial_state, total,
+                                          chunked, adaptive)
+                gamma_dev = jnp.asarray(config.gamma, jnp.float32)
             t0 = time.perf_counter()
-            run = self._runner(total, False, state, gamma_dev, tel, sampler)
             with tel.span("execute", chunk=0, it=0, n=total):
+                run = self._runner(total, False, state, gamma_dev, tel,
+                                   sampler)
                 state, stats = run(state, gamma_dev)
                 if tel.enabled:
                     jax.block_until_ready(stats.dual_obj)
-            tel.counter("solve.chunks")
-            tel.counter("solve.iterations", total)
-            if sampler is not None:
-                s = sampler.sample(where="solve", it=total)
-                tel.event("memory", it=total, chunk=0,
-                          **sampler.event_fields(s))
-                tel.manifest(**sampler.watermarks())
-            tel.event("solve_end", stop_reason=StopReason.MAX_ITERATIONS.value,
-                      iterations_run=total, converged=False,
-                      wall_s=time.perf_counter() - t0, checks=0,
-                      health_incidents=0)
-            return SolveResult(lam=state.lam, stats=stats,
-                               iterations_run=total, converged=False,
-                               stop_reason=StopReason.MAX_ITERATIONS,
-                               final_state=state)
+            with tel.span("finish"):
+                tel.counter("solve.chunks")
+                tel.counter("solve.iterations", total)
+                if sampler is not None:
+                    s = sampler.sample(where="solve", it=total)
+                    tel.event("memory", it=total, chunk=0,
+                              **sampler.event_fields(s))
+                    tel.manifest(**sampler.watermarks())
+                tel.event("solve_end",
+                          stop_reason=StopReason.MAX_ITERATIONS.value,
+                          iterations_run=total, converged=False,
+                          wall_s=time.perf_counter() - t0, checks=0,
+                          health_incidents=0)
+                return SolveResult(lam=state.lam, stats=stats,
+                                   iterations_run=total, converged=False,
+                                   stop_reason=StopReason.MAX_ITERATIONS,
+                                   final_state=state)
 
-        criteria = criteria if criteria is not None else StoppingCriteria()
-        check = max(1, int(criteria.check_every))
-        gamma_now = float(config.gamma_init) if adaptive else config.gamma
-        g_prev = None
-        it_done = 0
-        if initial_state is not None:
-            it_done = int(jax.device_get(initial_state.it))
-            meta = resume_meta or {}
-            if meta.get("gamma_now") is not None:
-                gamma_now = float(meta["gamma_now"])
-            if meta.get("g_prev") is not None:
-                g_prev = float(meta["g_prev"])
-        t0 = time.perf_counter()
-        stats_chunks = []
-        # keep-last diagnostics bound (SolveConfig.max_diagnostics): a
-        # million-iteration solve with a small check_every must not grow an
-        # unbounded host-side tuple; None (the default) keeps everything
-        diags = deque(maxlen=config.max_diagnostics)
-        health_recs = []
-        chunk_idx = 0
-        converged = False
-        stop_reason = StopReason.MAX_ITERATIONS
-        # Health-guard bookkeeping: the last-good snapshot and its
-        # baselines.  The snapshot is a private copy — the live state's
-        # buffers are donated chunk over chunk, the snapshot's never are.
-        snap = _copy_state(state) if health is not None else None
-        snap_it = it_done
-        snap_gamma_now = gamma_now
-        snap_g_prev = g_prev
-        snap_g = None          # trailing dual objective of the last-good chunk
-        snap_grad = None       # trailing ‖∇g‖ of the last-good chunk
-        snap_gamma = None      # trailing γ of the last-good chunk
-        fails = 0
+        with tel.span("start"):
+            state = self._start_state(tel, lam0, initial_state, total,
+                                      chunked, adaptive)
+            criteria = criteria if criteria is not None else StoppingCriteria()
+            check = max(1, int(criteria.check_every))
+            gamma_now = float(config.gamma_init) if adaptive else config.gamma
+            g_prev = None
+            it_done = 0
+            if initial_state is not None:
+                it_done = int(jax.device_get(initial_state.it))
+                meta = resume_meta or {}
+                if meta.get("gamma_now") is not None:
+                    gamma_now = float(meta["gamma_now"])
+                if meta.get("g_prev") is not None:
+                    g_prev = float(meta["g_prev"])
+            t0 = time.perf_counter()
+            stats_chunks = []
+            # keep-last diagnostics bound (SolveConfig.max_diagnostics): a
+            # million-iteration solve with a small check_every must not
+            # grow an unbounded host-side tuple; None (the default) keeps
+            # everything
+            diags = deque(maxlen=config.max_diagnostics)
+            health_recs = []
+            chunk_idx = 0
+            converged = False
+            stop_reason = StopReason.MAX_ITERATIONS
+            # Health-guard bookkeeping: the last-good snapshot and its
+            # baselines.  The snapshot is a private copy — the live state's
+            # buffers are donated chunk over chunk, the snapshot's never
+            # are.
+            snap = _copy_state(state) if health is not None else None
+            snap_it = it_done
+            snap_gamma_now = gamma_now
+            snap_g_prev = g_prev
+            snap_g = None      # trailing dual objective of the last-good chunk
+            snap_grad = None   # trailing ‖∇g‖ of the last-good chunk
+            snap_gamma = None  # trailing γ of the last-good chunk
+            fails = 0
 
-        def _meta(final: bool) -> dict:
-            meta = {"gamma_now": gamma_now, "g_prev": g_prev,
-                    "it": it_done, "final": final}
-            meta.update(self.rule.checkpoint_meta())
-            return meta
+            def _meta(final: bool) -> dict:
+                meta = {"gamma_now": gamma_now, "g_prev": g_prev,
+                        "it": it_done, "final": final}
+                meta.update(self.rule.checkpoint_meta())
+                return meta
+
+            def _preempted() -> bool:
+                """The poll before a chunk: True stops the loop."""
+                return (it_done < total and preempt_fn is not None
+                        and bool(preempt_fn()))
+
+            preempted = _preempted()
 
         try:
-            while it_done < total:
-                if preempt_fn is not None and preempt_fn():
-                    stop_reason = StopReason.PREEMPTED
-                    break
+            while it_done < total and not preempted:
                 n = min(check, total - it_done)
-                gamma_arr = jnp.asarray(gamma_now, jnp.float32)
-                run = self._runner(n, adaptive, state, gamma_arr, tel,
-                                   sampler)
-                if profiler is not None:
-                    profiler.chunk_start(chunk_idx, tel)
                 with tel.span("execute", chunk=chunk_idx, it=it_done, n=n):
+                    gamma_arr = jnp.asarray(gamma_now, jnp.float32)
+                    run = self._runner(n, adaptive, state, gamma_arr, tel,
+                                       sampler)
+                    if profiler is not None:
+                        profiler.chunk_start(chunk_idx, tel)
                     state, stats = run(state, gamma_arr)
                     if tel.enabled:
                         # the dispatch is async; wait here so the execute
                         # span measures device compute, not queue depth
                         # (numerics untouched — pure synchronization)
                         jax.block_until_ready(stats.dual_obj)
-                if self.chunk_fault_hook is not None:
-                    state, stats = self.chunk_fault_hook(it_done, state,
-                                                         stats)
+                    if self.chunk_fault_hook is not None:
+                        state, stats = self.chunk_fault_hook(it_done, state,
+                                                             stats)
 
                 # device→host: the chunk's trailing scalars (this is the
                 # sync point that keeps the hot path a single XLA program
@@ -420,148 +475,164 @@ class SolveEngine:
                     infeas = float(stats.infeas[-1])
                     grad_norm = float(stats.grad_norm[-1])
                     gamma_cur = float(stats.gamma[-1])
-                elapsed = time.perf_counter() - t0
-                if profiler is not None:
-                    profiler.chunk_end(chunk_idx, tel)
-                if sampler is not None:
-                    # the chunk boundary is the host sync point — the one
-                    # place a resource read can't perturb device pipelining
-                    s = sampler.sample(where="chunk", it=it_done + n)
-                    tel.event("memory", it=it_done + n, chunk=chunk_idx,
-                              **sampler.event_fields(s))
-                chunk_idx += 1
-                tel.counter("solve.chunks")
 
-                if health is not None:
-                    status = _classify_chunk(health, self.rule, state, g,
-                                             infeas, grad_norm, gamma_cur,
-                                             snap_g, snap_grad, snap_gamma)
-                    if status is not None:
-                        fails += 1
-                        scale = health.step_backoff ** fails
-                        if fails > health.max_retries:
+                with tel.span("control", chunk=chunk_idx, it=it_done):
+                    elapsed = time.perf_counter() - t0
+                    if profiler is not None:
+                        profiler.chunk_end(chunk_idx, tel)
+                    if sampler is not None:
+                        # the chunk boundary is the host sync point — the
+                        # one place a resource read can't perturb device
+                        # pipelining
+                        s = sampler.sample(where="chunk", it=it_done + n)
+                        tel.event("memory", it=it_done + n, chunk=chunk_idx,
+                                  **sampler.event_fields(s))
+                    chunk_idx += 1
+                    tel.counter("solve.chunks")
+
+                    if health is not None:
+                        status = _classify_chunk(
+                            health, self.rule, state, g, infeas, grad_norm,
+                            gamma_cur, snap_g, snap_grad, snap_gamma)
+                        if status is not None:
+                            fails += 1
+                            scale = health.step_backoff ** fails
+                            if fails > health.max_retries:
+                                rec = HealthRecord(
+                                    it=it_done + n, status=status,
+                                    action="giveup", retries=fails,
+                                    dual_obj=g, grad_norm=grad_norm,
+                                    gamma=gamma_cur, rolled_back_to=snap_it,
+                                    step_scale=scale)
+                                health_recs.append(rec)
+                                tel.event("health", **rec._asdict())
+                                state = _copy_state(snap)
+                                gamma_now = snap_gamma_now
+                                g_prev = snap_g_prev
+                                stop_reason = StopReason.DIVERGED
+                                break
                             rec = HealthRecord(
                                 it=it_done + n, status=status,
-                                action="giveup", retries=fails, dual_obj=g,
-                                grad_norm=grad_norm, gamma=gamma_cur,
-                                rolled_back_to=snap_it, step_scale=scale)
+                                action="rollback", retries=fails,
+                                dual_obj=g, grad_norm=grad_norm,
+                                gamma=gamma_cur, rolled_back_to=snap_it,
+                                step_scale=scale)
                             health_recs.append(rec)
                             tel.event("health", **rec._asdict())
-                            state = _copy_state(snap)
-                            gamma_now = snap_gamma_now
+                            tel.counter("solve.rollbacks")
+                            state = self.rule.apply_backoff(
+                                _copy_state(snap), config, snap_gamma_now,
+                                scale)
+                            if adaptive:
+                                # γ backoff: retry under heavier
+                                # regularization; the stall decay walks it
+                                # back down afterwards
+                                boosted = min(
+                                    snap_gamma_now
+                                    * health.gamma_backoff ** fails,
+                                    float(config.gamma_init))
+                                if boosted != gamma_now:
+                                    tel.event("gamma", it=it_done,
+                                              gamma_from=gamma_now,
+                                              gamma_to=boosted,
+                                              reason="health_backoff")
+                                gamma_now = boosted
                             g_prev = snap_g_prev
-                            stop_reason = StopReason.DIVERGED
-                            break
-                        rec = HealthRecord(
-                            it=it_done + n, status=status, action="rollback",
-                            retries=fails, dual_obj=g, grad_norm=grad_norm,
-                            gamma=gamma_cur, rolled_back_to=snap_it,
-                            step_scale=scale)
-                        health_recs.append(rec)
-                        tel.event("health", **rec._asdict())
-                        tel.counter("solve.rollbacks")
-                        state = self.rule.apply_backoff(_copy_state(snap),
-                                                        config,
-                                                        snap_gamma_now, scale)
-                        if adaptive:
-                            # γ backoff: retry under heavier regularization;
-                            # the stall decay walks it back down afterwards
-                            boosted = min(
-                                snap_gamma_now * health.gamma_backoff ** fails,
-                                float(config.gamma_init))
-                            if boosted != gamma_now:
-                                tel.event("gamma", it=it_done,
-                                          gamma_from=gamma_now,
-                                          gamma_to=boosted,
-                                          reason="health_backoff")
-                            gamma_now = boosted
-                        g_prev = snap_g_prev
-                        # the bad chunk's stats are discarded; the iteration
-                        # counter never advanced, so γ schedules rewind too
-                        continue
-                    fails = 0
+                            # the bad chunk's stats are discarded; the
+                            # iteration counter never advanced, so γ
+                            # schedules rewind too
+                            preempted = _preempted()
+                            continue
+                        fails = 0
 
-                it_done += n
-                tel.counter("solve.iterations", n)
-                stats_chunks.append(stats)
-                if g_prev is None:
-                    rel_dual = (abs(g - float(stats.dual_obj[0]))
-                                / max(1.0, abs(g)) if n > 1 else float("inf"))
-                else:
-                    rel_dual = abs(g - g_prev) / max(1.0, abs(g))
-                g_prev = g
+                    it_done += n
+                    tel.counter("solve.iterations", n)
+                    stats_chunks.append(stats)
+                    if g_prev is None:
+                        rel_dual = (abs(g - float(stats.dual_obj[0]))
+                                    / max(1.0, abs(g)) if n > 1
+                                    else float("inf"))
+                    else:
+                        rel_dual = abs(g - g_prev) / max(1.0, abs(g))
+                    g_prev = g
 
-                at_target = gamma_cur <= config.gamma * (1.0 + 1e-6)
-                stalled = rel_dual < config.gamma_stall_tol
-                if adaptive and not at_target and stalled:
-                    decayed = max(gamma_now * config.gamma_decay_rate,
-                                  config.gamma)
-                    if decayed != gamma_now:
-                        tel.event("gamma", it=it_done, gamma_from=gamma_now,
-                                  gamma_to=decayed, reason="stall_decay")
-                    gamma_now = decayed
-                rec = ConvergenceCheck(it=it_done, dual_obj=g,
-                                       rel_dual=rel_dual,
-                                       infeas=infeas, grad_norm=grad_norm,
-                                       gamma=gamma_cur, elapsed=elapsed,
-                                       stalled=stalled)
-                diags.append(rec)
-                tel.event("check", **rec._asdict())
-                if diagnostics_fn is not None:
-                    diagnostics_fn(rec)
-                if health is not None:
-                    snap = _copy_state(state)
-                    snap_it = it_done
-                    snap_gamma_now = gamma_now
-                    snap_g_prev = g_prev
-                    snap_g, snap_grad, snap_gamma = g, grad_norm, gamma_cur
-                if checkpoint_fn is not None:
-                    with tel.span("checkpoint", it=it_done):
-                        checkpoint_fn(it_done, state, _meta(final=False))
-                    tel.event("checkpoint", it=it_done, final=False)
+                    at_target = gamma_cur <= config.gamma * (1.0 + 1e-6)
+                    stalled = rel_dual < config.gamma_stall_tol
+                    if adaptive and not at_target and stalled:
+                        decayed = max(gamma_now * config.gamma_decay_rate,
+                                      config.gamma)
+                        if decayed != gamma_now:
+                            tel.event("gamma", it=it_done,
+                                      gamma_from=gamma_now,
+                                      gamma_to=decayed, reason="stall_decay")
+                        gamma_now = decayed
+                    rec = ConvergenceCheck(it=it_done, dual_obj=g,
+                                           rel_dual=rel_dual,
+                                           infeas=infeas, grad_norm=grad_norm,
+                                           gamma=gamma_cur, elapsed=elapsed,
+                                           stalled=stalled)
+                    diags.append(rec)
+                    tel.event("check", **rec._asdict())
+                    if diagnostics_fn is not None:
+                        diagnostics_fn(rec)
+                    if health is not None:
+                        snap = _copy_state(state)
+                        snap_it = it_done
+                        snap_gamma_now = gamma_now
+                        snap_g_prev = g_prev
+                        snap_g, snap_grad, snap_gamma = g, grad_norm, gamma_cur
+                    if checkpoint_fn is not None:
+                        with tel.span("checkpoint", it=it_done):
+                            checkpoint_fn(it_done, state, _meta(final=False))
+                        tel.event("checkpoint", it=it_done, final=False)
 
-                # tolerance checks only count once γ has reached its target:
-                # g and x*(λ) move with γ, so earlier "convergence" is
-                # spurious
-                if at_target and criteria.satisfied(rel_dual, infeas,
-                                                    grad_norm, infeas_scale):
-                    converged = True
-                    stop_reason = StopReason.CONVERGED
-                    break
-                if (criteria.max_seconds is not None
-                        and elapsed >= criteria.max_seconds):
-                    stop_reason = StopReason.MAX_SECONDS
-                    break
+                    # tolerance checks only count once γ has reached its
+                    # target: g and x*(λ) move with γ, so earlier
+                    # "convergence" is spurious
+                    if at_target and criteria.satisfied(
+                            rel_dual, infeas, grad_norm, infeas_scale):
+                        converged = True
+                        stop_reason = StopReason.CONVERGED
+                        break
+                    if (criteria.max_seconds is not None
+                            and elapsed >= criteria.max_seconds):
+                        stop_reason = StopReason.MAX_SECONDS
+                        break
+                    preempted = _preempted()
         finally:
             if profiler is not None:
                 # a solve that raises / diverges / preempts mid-window must
                 # still flush a valid trace
                 profiler.stop(tel)
 
-        if checkpoint_fn is not None:
-            with tel.span("checkpoint", it=it_done):
-                checkpoint_fn(it_done, state, _meta(final=True))
-            tel.event("checkpoint", it=it_done, final=True)
-        if not stats_chunks:
-            stats = IterStats(*(jnp.zeros((0,), jnp.float32)
-                                for _ in IterStats._fields))
-        elif len(stats_chunks) == 1:
-            stats = stats_chunks[0]
-        else:
-            stats = jax.tree.map(lambda *xs: jnp.concatenate(xs),
-                                 *stats_chunks)
-        if sampler is not None:
-            # run-level peaks stamped into the manifest (the LAST manifest
-            # record in a log carries the complete merged view)
-            tel.manifest(**sampler.watermarks())
-        tel.event("solve_end", stop_reason=stop_reason.value,
-                  iterations_run=it_done, converged=converged,
-                  wall_s=time.perf_counter() - t0, checks=len(diags),
-                  health_incidents=len(health_recs))
-        return SolveResult(lam=state.lam, stats=stats, iterations_run=it_done,
-                           converged=converged, stop_reason=stop_reason,
-                           diagnostics=tuple(diags),
-                           health=tuple(health_recs), final_state=state)
+        with tel.span("finish"):
+            if preempted:
+                stop_reason = StopReason.PREEMPTED
+            if checkpoint_fn is not None:
+                with tel.span("checkpoint", it=it_done):
+                    checkpoint_fn(it_done, state, _meta(final=True))
+                tel.event("checkpoint", it=it_done, final=True)
+            if not stats_chunks:
+                stats = IterStats(*(jnp.zeros((0,), jnp.float32)
+                                    for _ in IterStats._fields))
+            elif len(stats_chunks) == 1:
+                stats = stats_chunks[0]
+            else:
+                stats = jax.tree.map(lambda *xs: jnp.concatenate(xs),
+                                     *stats_chunks)
+            if sampler is not None:
+                # run-level peaks stamped into the manifest (the LAST
+                # manifest record in a log carries the complete merged view)
+                tel.manifest(**sampler.watermarks())
+            tel.event("solve_end", stop_reason=stop_reason.value,
+                      iterations_run=it_done, converged=converged,
+                      wall_s=time.perf_counter() - t0, checks=len(diags),
+                      health_incidents=len(health_recs))
+            return SolveResult(lam=state.lam, stats=stats,
+                               iterations_run=it_done, converged=converged,
+                               stop_reason=stop_reason,
+                               diagnostics=tuple(diags),
+                               health=tuple(health_recs), final_state=state)
 
 
 def _infeas_scale(obj, criteria: Optional[StoppingCriteria]) -> float:

@@ -49,6 +49,14 @@ from .types import AxPlan, LPData, Slab, dest_gather, edge_space
 
 AX_MODES = ("scatter", "sorted", "aligned", "aligned_gvals")
 
+# Op-name scopes of the sweep's stages (DESIGN.md §11).  Trace-time
+# metadata only: they name each device op's stage in a profile and change
+# neither numerics nor fusion.
+LAMBDA_GATHER = "sweep.lambda_gather"   # λ read at each edge, Aᵀλ
+PROJECT = "sweep.project"               # u, x = Π_C(u), cᵀx and ‖x‖²
+AX = "sweep.ax"                         # the Ax reduction by destination
+COLLECTIVE = "sweep.collective"         # the cross-shard sums, g, ∇g, infeas
+
 
 class ObjectiveAux(NamedTuple):
     primal_obj: jax.Array   # cᵀx*(λ)
@@ -63,12 +71,15 @@ def slab_xstar(slab: Slab, lam: jax.Array, gamma: jax.Array,
     """x*(λ) for one slab: gather λ, form u, project.  Returns (n, w)."""
     if use_pallas:
         from repro.kernels import ops as kops
-        return kops.dual_xstar(slab, lam, gamma, proj_kind, proj_iters)
-    lam_e = dest_gather(lam, slab.dest_idx)             # (m, n, w)
-    atl = jnp.einsum("nwm,mnw->nw", slab.a_vals, lam_e)  # (Aᵀλ) at edges
-    u = -(atl + slab.c_vals) / gamma
-    return projections.project(proj_kind, u, slab.ub, slab.s, slab.mask,
-                               iters=proj_iters)
+        with jax.named_scope(PROJECT):
+            return kops.dual_xstar(slab, lam, gamma, proj_kind, proj_iters)
+    with jax.named_scope(LAMBDA_GATHER):
+        lam_e = dest_gather(lam, slab.dest_idx)             # (m, n, w)
+        atl = jnp.einsum("nwm,mnw->nw", slab.a_vals, lam_e)  # Aᵀλ at edges
+    with jax.named_scope(PROJECT):
+        u = -(atl + slab.c_vals) / gamma
+        return projections.project(proj_kind, u, slab.ub, slab.s, slab.mask,
+                                   iters=proj_iters)
 
 
 def slab_xgvals(slab: Slab, lam: jax.Array, gamma: jax.Array,
@@ -89,25 +100,30 @@ def slab_xgvals(slab: Slab, lam: jax.Array, gamma: jax.Array,
         from repro.kernels import ops as kops
         kslab = (slab if shift is None
                  else slab._replace(c_vals=slab.c_vals + shift))
-        x, gvals, c_x, x_sq = kops.dual_grad_full(
-            kslab, lam, gamma, proj_kind, proj_iters)
+        with jax.named_scope(PROJECT):
+            x, gvals, c_x, x_sq = kops.dual_grad_full(
+                kslab, lam, gamma, proj_kind, proj_iters)
+            if shift is not None:
+                # kernel saw c+μ, so its cᵀx includes the shift term (x is
+                # 0 on padding); subtract it back out
+                if jnp.ndim(shift):
+                    c_x = c_x - jnp.vdot(shift, x)
+                else:
+                    c_x = c_x - shift * jnp.sum(x)
+            return x, gvals, c_x, x_sq
+    with jax.named_scope(LAMBDA_GATHER):
+        lam_e = dest_gather(lam, slab.dest_idx)
+        atl = jnp.einsum("nwm,mnw->nw", slab.a_vals, lam_e)
         if shift is not None:
-            # kernel saw c+μ, so its cᵀx includes the shift term (x is 0 on
-            # padding); subtract it back out
-            if jnp.ndim(shift):
-                c_x = c_x - jnp.vdot(shift, x)
-            else:
-                c_x = c_x - shift * jnp.sum(x)
-        return x, gvals, c_x, x_sq
-    lam_e = dest_gather(lam, slab.dest_idx)
-    atl = jnp.einsum("nwm,mnw->nw", slab.a_vals, lam_e)
-    if shift is not None:
-        atl = atl + shift
-    u = -(atl + slab.c_vals) / gamma
-    x = projections.project(proj_kind, u, slab.ub, slab.s, slab.mask,
-                            iters=proj_iters)
-    gvals = slab.a_vals * x[..., None]                  # (n, w, m)
-    return x, gvals, jnp.vdot(slab.c_vals, x), jnp.vdot(x, x)
+            atl = atl + shift
+    with jax.named_scope(PROJECT):
+        u = -(atl + slab.c_vals) / gamma
+        x = projections.project(proj_kind, u, slab.ub, slab.s, slab.mask,
+                                iters=proj_iters)
+        c_x, x_sq = jnp.vdot(slab.c_vals, x), jnp.vdot(x, x)
+    with jax.named_scope(AX):
+        gvals = slab.a_vals * x[..., None]                  # (n, w, m)
+    return x, gvals, c_x, x_sq
 
 
 def slab_xcarry(slab: Slab, lam: jax.Array, gamma: jax.Array,
@@ -127,23 +143,26 @@ def slab_xcarry(slab: Slab, lam: jax.Array, gamma: jax.Array,
         from repro.kernels import ops as kops
         kslab = (slab if shift is None
                  else slab._replace(c_vals=slab.c_vals + shift))
-        x, c_x, x_sq = kops.dual_x_full(kslab, lam, gamma, proj_kind,
-                                        proj_iters)
+        with jax.named_scope(PROJECT):
+            x, c_x, x_sq = kops.dual_x_full(kslab, lam, gamma, proj_kind,
+                                            proj_iters)
+            if shift is not None:
+                # kernel saw c+μ: subtract the shift term back out of cᵀx
+                if jnp.ndim(shift):
+                    c_x = c_x - jnp.vdot(shift, x)
+                else:
+                    c_x = c_x - shift * jnp.sum(x)
+            return x, c_x, x_sq
+    with jax.named_scope(LAMBDA_GATHER):
+        lam_e = dest_gather(lam, slab.dest_idx)
+        atl = jnp.einsum("nwm,mnw->nw", slab.a_vals, lam_e)
         if shift is not None:
-            # kernel saw c+μ: subtract the shift term back out of cᵀx
-            if jnp.ndim(shift):
-                c_x = c_x - jnp.vdot(shift, x)
-            else:
-                c_x = c_x - shift * jnp.sum(x)
-        return x, c_x, x_sq
-    lam_e = dest_gather(lam, slab.dest_idx)
-    atl = jnp.einsum("nwm,mnw->nw", slab.a_vals, lam_e)
-    if shift is not None:
-        atl = atl + shift
-    u = -(atl + slab.c_vals) / gamma
-    x = projections.project(proj_kind, u, slab.ub, slab.s, slab.mask,
-                            iters=proj_iters)
-    return x, jnp.vdot(slab.c_vals, x), jnp.vdot(x, x)
+            atl = atl + shift
+    with jax.named_scope(PROJECT):
+        u = -(atl + slab.c_vals) / gamma
+        x = projections.project(proj_kind, u, slab.ub, slab.s, slab.mask,
+                                iters=proj_iters)
+        return x, jnp.vdot(slab.c_vals, x), jnp.vdot(x, x)
 
 
 def _segment_ax(gvals_flat: jax.Array, flat_dest: jax.Array,
@@ -163,8 +182,9 @@ def slab_contribution(slab: Slab, lam: jax.Array, gamma: jax.Array,
     """One slab's (Ax partial, cᵀx, ‖x‖²) via the destination scatter."""
     x, gvals, c_x, x_sq = slab_xgvals(slab, lam, gamma, proj_kind,
                                       proj_iters, use_pallas)
-    ax = _segment_ax(edge_space(gvals), edge_space(slab.dest_idx),
-                     num_destinations)
+    with jax.named_scope(AX):
+        ax = _segment_ax(edge_space(gvals), edge_space(slab.dest_idx),
+                         num_destinations)
     return ax, c_x, x_sq
 
 
@@ -292,27 +312,28 @@ class MatchingObjective:
         O(E·m)); for every gvals mode they are (n·w, m) per-edge gradient
         values.
         """
-        lp = self.lp
-        J = lp.num_destinations
-        if self.ax_mode == "aligned":
-            from repro.kernels import ops as kops
-            return kops.ax_aligned_x(self._plan, jnp.concatenate(parts),
-                                     use_pallas=self.use_pallas,
-                                     out_dtype=dtype)
-        if self.ax_mode == "aligned_gvals":
-            from repro.kernels import ops as kops
-            return kops.ax_aligned(self._plan,
-                                   jnp.concatenate(parts, axis=0),
-                                   use_pallas=self.use_pallas,
-                                   out_dtype=dtype)
-        if self.ax_mode == "sorted":
-            gvals = jnp.concatenate(parts, axis=0)[self._perm]
-            return _segment_ax(gvals, self._sorted_dest, J,
-                               indices_are_sorted=True)
-        ax = jnp.zeros((lp.m, J), dtype)
-        for slab, part in zip(lp.slabs, parts):
-            ax = ax + _segment_ax(part, edge_space(slab.dest_idx), J)
-        return ax
+        with jax.named_scope(AX):
+            lp = self.lp
+            J = lp.num_destinations
+            if self.ax_mode == "aligned":
+                from repro.kernels import ops as kops
+                return kops.ax_aligned_x(self._plan, jnp.concatenate(parts),
+                                         use_pallas=self.use_pallas,
+                                         out_dtype=dtype)
+            if self.ax_mode == "aligned_gvals":
+                from repro.kernels import ops as kops
+                return kops.ax_aligned(self._plan,
+                                       jnp.concatenate(parts, axis=0),
+                                       use_pallas=self.use_pallas,
+                                       out_dtype=dtype)
+            if self.ax_mode == "sorted":
+                gvals = jnp.concatenate(parts, axis=0)[self._perm]
+                return _segment_ax(gvals, self._sorted_dest, J,
+                                   indices_are_sorted=True)
+            ax = jnp.zeros((lp.m, J), dtype)
+            for slab, part in zip(lp.slabs, parts):
+                ax = ax + _segment_ax(part, edge_space(slab.dest_idx), J)
+            return ax
 
     def _forward(self, lam: jax.Array, gamma: jax.Array, shift=None,
                  with_xsum: bool = False):
@@ -344,11 +365,12 @@ class MatchingObjective:
 
     def calculate(self, lam: jax.Array, gamma: jax.Array):
         ax, c_x, x_sq, _ = self._forward(lam, gamma)
-        if self.ax_reducer is not None:
-            ax, c_x, x_sq = self.ax_reducer((ax, c_x, x_sq))
-        grad = ax - self.lp.b
-        g = c_x + 0.5 * gamma * x_sq + jnp.vdot(lam, grad)
-        infeas = jnp.linalg.norm(jnp.maximum(grad, 0.0))
+        with jax.named_scope(COLLECTIVE):
+            if self.ax_reducer is not None:
+                ax, c_x, x_sq = self.ax_reducer((ax, c_x, x_sq))
+            grad = ax - self.lp.b
+            g = c_x + 0.5 * gamma * x_sq + jnp.vdot(lam, grad)
+            infeas = jnp.linalg.norm(jnp.maximum(grad, 0.0))
         return g, grad, ObjectiveAux(primal_obj=c_x, x_sq=x_sq, ax=ax,
                                      infeas=infeas)
 

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import spanned
+
 from .types import LPData, Slab, dest_gather, edge_space
 
 
@@ -104,6 +106,7 @@ def undo_primal_scaling(xs, scaling: PrimalScaling):
     return [z / v[:, None] for z, v in zip(xs, scaling.v)]
 
 
+@spanned("build.precondition")
 def precondition(lp: LPData, row_norm: bool = True, primal: bool = False):
     """Convenience: apply the §5.1 transforms; returns (lp', undo_info)."""
     row_scaling = None

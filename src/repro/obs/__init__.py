@@ -3,7 +3,9 @@
 Structured run logs (JSONL events + manifest), nestable wall-clock trace
 spans, monotonic counters/gauges, a leveled console logger mirrored into
 the sink, and an opt-in jax.profiler window.  `Telemetry.disabled()` is
-the zero-cost default threaded through SolveEngine and AllocationServer;
+the no-op default threaded through SolveEngine and AllocationServer; its
+spans, like every recorder's, still reach a profiler trace as
+`repro.<name>` TraceMes and the per-name `span_totals()`.
 `launch/report.py` renders a post-mortem from any emitted run log.
 
 The live side (DESIGN.md §13): `metrics` is the scrapeable plane —
@@ -13,7 +15,8 @@ sampler (host RSS via procfs, device HBM stats where the backend
 reports them, per-runner compiled estimates) whose watermarks the
 engine stamps into the manifest.
 """
-from .telemetry import JsonlSink, ListSink, Telemetry, LEVELS
+from .telemetry import (JsonlSink, ListSink, Telemetry, LEVELS, note_op_scopes,
+                        op_scopes, span_totals, spanned)
 from .schema import (EVENT_FIELDS, RunLog, SchemaError, iter_events,
                      load_run, validate_event, validate_run)
 from .profile import ProfilerHook
@@ -27,6 +30,7 @@ from .memory import (MemorySample, MemorySampler, compiled_memory_estimate,
 
 __all__ = [
     "Telemetry", "JsonlSink", "ListSink", "LEVELS",
+    "span_totals", "spanned", "note_op_scopes", "op_scopes",
     "EVENT_FIELDS", "RunLog", "SchemaError", "iter_events", "load_run",
     "validate_event", "validate_run",
     "ProfilerHook",

@@ -8,7 +8,10 @@ a benchmark row).  It records four kinds of signal:
                 `obs/schema.py` and every record is validated on read;
   * spans     — nestable wall-clock sections (`with tel.span("compile")`),
                 emitted as `span` events carrying the slash-joined nesting
-                path and the duration;
+                path and the duration.  Every span, on any recorder, is
+                also a `jax.profiler` TraceMe named `repro.<name>` — on
+                the profiler's host plane, on the device timeline's clock
+                — and adds its seconds to the process-wide `span_totals()`;
   * counters / gauges — in-memory monotonic counts and last-value gauges,
                 readable any time via `metrics_snapshot()` and flushed as
                 one `counters` record by `close()`;
@@ -25,7 +28,10 @@ is a console logger + metrics registry (events are dropped).
 everywhere in the engine and server, so the healthy solve path with no
 telemetry attached is bitwise identical to the pre-telemetry code
 (asserted in tests/test_telemetry.py, the same standard as DESIGN.md
-§4/§9/§10 bit-identity guarantees).
+§4/§9/§10 bit-identity guarantees).  It records nothing, but its spans
+still annotate a profiler trace and count in `span_totals()`: a traced
+window shows the engine's phases whether or not a run log is kept.  When
+no trace is active a TraceMe costs one "is tracing on" check.
 
 All records are JSON-sanitized at emission: non-finite floats become
 null (a NaN dual objective from a diverging run must not produce an
@@ -43,18 +49,83 @@ of splicing into each other's.
 """
 from __future__ import annotations
 
+import functools
 import json
 import math
 import os
+import re
 import sys
 import threading
 import time
 import uuid
-from typing import Any, Dict, List, Optional, TextIO
+from typing import Any, Callable, Dict, List, Optional, TextIO, Tuple
 
-__all__ = ["Telemetry", "JsonlSink", "ListSink", "LEVELS"]
+__all__ = ["Telemetry", "JsonlSink", "ListSink", "LEVELS", "span_totals",
+           "spanned", "note_op_scopes", "op_scopes"]
 
 LEVELS = {"debug": 10, "info": 20, "warning": 30, "error": 40}
+
+TRACE_PREFIX = "repro."      # a span's TraceMe is named TRACE_PREFIX + name
+
+try:  # fails soft, as the manifest stamp does: Telemetry never needs jax
+    from jax.profiler import TraceAnnotation as _TraceMe
+except ImportError:  # pragma: no cover
+    from contextlib import nullcontext as _TraceMe
+
+# process-wide tables for readers of a trace (`span_totals`, `op_scopes`)
+_tables_lock = threading.Lock()
+_totals: Dict[str, List[float]] = {}
+
+
+def _add_total(name: str, seconds: float) -> None:
+    with _tables_lock:
+        t = _totals.get(name)
+        if t is None:
+            _totals[name] = [seconds, 1]
+        else:
+            t[0] += seconds
+            t[1] += 1
+
+
+def span_totals() -> Dict[str, Tuple[float, int]]:
+    """Host seconds and count of every span closed in this process so far,
+    by span name, over every recorder (the disabled one included)."""
+    with _tables_lock:
+        return {k: (v[0], int(v[1])) for k, v in _totals.items()}
+
+
+# An instruction of a compiled program and the op-name path in its metadata;
+# the innermost op-name scope of the solve loop on such a path.
+_HLO_OP = re.compile(r'^\s*(?:ROOT\s+)?(%[^\s=]+) = [^\n]*?'
+                     r'metadata=\{[^}\n]*?op_name="([^"\n]*)"', re.M)
+_SCOPE = re.compile(r'(?:^|/)(sweep(?:\.\w+)?|update)(?=/)')
+_op_scopes: Dict[str, str] = {}
+
+
+def note_op_scopes(compiled) -> None:
+    """Record the innermost solve-loop scope (`update`, `sweep`,
+    `sweep.*`) of every instruction of a compiled program.
+
+    On a TPU the profiler names each device operation by its instruction
+    text alone; the op-name path its scopes live on stays in the program.
+    This table joins the two: `op_scopes()[name]` for the `%name` an event
+    name starts with."""
+    text = compiled.as_text()
+    if not text:
+        return
+    found = {}
+    for name, op_name in _HLO_OP.findall(text):
+        scopes = _SCOPE.findall(op_name)
+        if scopes:
+            found[name] = scopes[-1]
+    with _tables_lock:
+        _op_scopes.update(found)
+
+
+def op_scopes() -> Dict[str, str]:
+    """Instruction name → innermost scope, over every program noted."""
+    with _tables_lock:
+        return dict(_op_scopes)
 
 
 def _json_safe(v: Any) -> Any:
@@ -125,27 +196,50 @@ class ListSink:
         pass
 
 
-class _Span:
-    """One nestable wall-clock section; emitted as a `span` event on exit."""
+class _TraceSpan:
+    """A span that reaches the profiler trace and `span_totals()` only: the
+    disabled recorder's span, and the base of every other."""
 
-    __slots__ = ("_tel", "name", "path", "fields", "t0")
+    __slots__ = ("name", "t0", "_trace_me")
 
-    def __init__(self, tel: "Telemetry", name: str, fields: Dict[str, Any]):
-        self._tel = tel
+    def __init__(self, name: str):
         self.name = name
-        self.fields = fields
-        self.path = ""
         self.t0 = 0.0
+        self._trace_me = _TraceMe(TRACE_PREFIX + name)
 
-    def __enter__(self) -> "_Span":
-        tel = self._tel
-        tel._stack.append(self.name)
-        self.path = "/".join(tel._stack)
+    def __enter__(self):
+        self._trace_me.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         dur = time.perf_counter() - self.t0
+        self._trace_me.__exit__(exc_type, exc, tb)
+        _add_total(self.name, dur)
+        self._done(dur)
+
+    def _done(self, dur: float) -> None:
+        pass
+
+
+class _Span(_TraceSpan):
+    """One nestable wall-clock section; emitted as a `span` event on exit."""
+
+    __slots__ = ("_tel", "path", "fields")
+
+    def __init__(self, tel: "Telemetry", name: str, fields: Dict[str, Any]):
+        super().__init__(name)
+        self._tel = tel
+        self.fields = fields
+        self.path = ""
+
+    def __enter__(self) -> "_Span":
+        tel = self._tel
+        tel._stack.append(self.name)
+        self.path = "/".join(tel._stack)
+        return super().__enter__()
+
+    def _done(self, dur: float) -> None:
         tel = self._tel
         if tel._stack and tel._stack[-1] == self.name:
             tel._stack.pop()
@@ -153,23 +247,23 @@ class _Span:
                    "dur_s": dur, **self.fields})
 
 
-class _NullSpan:
-    """Reusable no-op context manager for the disabled singleton."""
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        return None
-
-
-_NULL_SPAN = _NullSpan()
+def spanned(name: str) -> Callable:
+    """Decorator: every call of the function runs inside
+    `Telemetry.disabled().span(name)` — for library steps that have no
+    recorder at hand (the instance build's `build.*` steps)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with _TraceSpan(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
 
 
 class Telemetry:
     """The run recorder (module doc).  Construct with a sink to persist a
     run log, without one for a console logger + metrics registry, or use
-    `Telemetry.disabled()` for the zero-cost default."""
+    `Telemetry.disabled()` for the no-op default."""
 
     enabled = True
 
@@ -312,9 +406,10 @@ class Telemetry:
 
 
 class _DisabledTelemetry(Telemetry):
-    """Zero-cost no-op: every method returns immediately.  The engine and
-    server default to this, keeping the untelemetered path identical to
-    the pre-telemetry code."""
+    """No-op recorder: every method returns immediately, but for `span`,
+    whose TraceMe and `span_totals()` entry stay (module doc).  The engine
+    and server default to this, keeping the untelemetered path identical
+    to the pre-telemetry code."""
 
     enabled = False
 
@@ -334,7 +429,7 @@ class _DisabledTelemetry(Telemetry):
         pass
 
     def span(self, name, **fields):
-        return _NULL_SPAN
+        return _TraceSpan(name)
 
     def counter(self, name, n=1):
         return 0
