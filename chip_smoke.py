@@ -124,7 +124,7 @@ def phase_correctness(seed: int, clock: CompileClock,
     import numpy as np
     import jax
     import jax.numpy as jnp
-    from repro.core import MatchingObjective
+    from repro.core import MatchingObjective, build_ax_plan
     from repro.core import baseline_numpy as bn
     from repro.core.types import edge_space
     from repro.kernels import ops, ref
@@ -158,7 +158,10 @@ def phase_correctness(seed: int, clock: CompileClock,
                                           s.mask, s.ub, s.s, lam, gamma)
         xs.append(edge_space(x))
         c_x, x_sq = c_x + float(cx), x_sq + float(sq)
-    ax = np.asarray(ref.ax_plan_x_ref(xla._plan, jnp.concatenate(xs)),
+    # the oracle reduces over an AxPlan, whichever Ax path the objective
+    # took (the dual tile on a TPU)
+    plan = jax.tree.map(jnp.asarray, build_ax_plan(lp))
+    ax = np.asarray(ref.ax_plan_x_ref(plan, jnp.concatenate(xs)),
                     np.float64)
     grad_ref = ax - np.asarray(lp.b, np.float64)
     g_ref = c_x + 0.025 * x_sq + float(np.vdot(np.asarray(lam), grad_ref))
@@ -181,7 +184,7 @@ def phase_correctness(seed: int, clock: CompileClock,
               and abs(float(sqk - sqr)) < 1e-3 * max(1.0, abs(float(sqr))),
               f"Pallas scalars differ from XLA on slab width {s.width}")
     pallas = MatchingObjective(lp, ax_mode="aligned", use_pallas=True,
-                               ax_plan=xla._plan)
+                               ax_plan=plan)
     compiled = jax.jit(pallas.calculate).lower(lam, gamma).compile()
     check("tpu_custom_call" in compiled.as_text(),
           "compiled Pallas evaluation holds no Mosaic kernel")
@@ -251,8 +254,9 @@ def phase_serve(seed: int, clock: CompileClock, duals: str,
     gamma = np.float32(meta["achieved_gamma"])
     say("[serve] slabs (n, w): "
         + " ".join(f"{s.n}x{s.width}" for s in lp.slabs)
-        + "; plan buckets (r, w): "
-        + " ".join(f"{b.rows}x{b.width}" for b in obj._plan.buckets))
+        + f"; Ax path {obj.ax_path}"
+        + ("" if obj._plan is None else "; plan buckets (r, w): " + " ".join(
+            f"{b.rows}x{b.width}" for b in obj._plan.buckets)))
     static = sum(leaf.nbytes for leaf in jax.tree.leaves((lp, obj._plan)))
     say(f"[serve] edges {lp.num_edges} padded, "
         f"{sum(int(s.mask.sum()) for s in lp.slabs)} real; instance + plan "

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.obs import spanned
+from repro.obs import note_ax_path, spanned
 
 from . import objectives
 from .maximizer import _infeas_scale, maximize
@@ -91,16 +91,19 @@ class DistributedMatchingObjective:
     proj_iters: int = 40
     use_pallas: bool = False
     lambda_axis: Optional[str] = None   # beyond-paper λ sharding
-    # "scatter" (paper-faithful segment-sum), "aligned" (value-carrying
-    # destination-major AxPlan: x-only reduce through the static a_dm copy,
+    # "scatter" (paper-faithful segment-sum), "aligned" (the x-carry sweep,
     # no gvals materialization — DESIGN.md §3), or "aligned_gvals" (the
-    # index-only aligned gather-reduce over materialized gvals).  With the
-    # aligned modes a per-shard plan over each device's local slab-edge
-    # space is built once — a_dm stacked alongside edge_idx/mask for
-    # "aligned" — and its leading shard axis is partitioned over
-    # source_axes — row-wise over the λ axis too when
-    # lambda_sharding="model" makes it one.
+    # index-only aligned gather-reduce over materialized gvals).  "aligned"
+    # reduces each shard's Ax on the dual tile where
+    # `objectives.select_ax_path` picks it, else through the value-carrying
+    # AxPlan.  A plan is built once per shard over its local slab-edge
+    # space, and its leading shard axis is partitioned over source_axes —
+    # row-wise over the λ axis too when lambda_sharding="model" makes it
+    # one.
     ax_mode: str = "scatter"
+    # the x-carry sweep's Ax path ("dual_tile" or "ax_plan"); None for the
+    # gvals modes
+    ax_path: Optional[str] = dataclasses.field(default=None, init=False)
     _plan: Optional[AxPlan] = dataclasses.field(
         default=None, init=False, repr=False)
 
@@ -109,7 +112,12 @@ class DistributedMatchingObjective:
             raise ValueError(
                 f"distributed ax_mode is 'scatter', 'aligned' or "
                 f"'aligned_gvals', got {self.ax_mode!r}")
-        if self.ax_mode in ("aligned", "aligned_gvals"):
+        if self.ax_mode == "aligned":
+            self.ax_path = objectives.select_ax_path(
+                objectives.platform_of(list(self.mesh.devices.flat)),
+                self.lambda_axis, self.lp.m, self.lp.num_destinations)
+            note_ax_path(self.ax_path)
+        if self.ax_mode == "aligned_gvals" or self.ax_path == "ax_plan":
             from .instance import build_sharded_ax_plan
             n_shards = int(np.prod([self.mesh.shape[a]
                                     for a in self.source_axes]))
@@ -159,7 +167,7 @@ class DistributedMatchingObjective:
                 "sources; pass source_axes containing lambda_axis")
         other_axes = tuple(a for a in source_axes if a != lam_axis)
 
-        ax_mode = self.ax_mode
+        ax_mode, ax_path = self.ax_mode, self.ax_path
         row_spec = P(source_axes)
         slab_specs = tuple(Slab(*(row_spec,) * 7) for _ in self.lp.slabs)
         b_spec = P(None, lam_axis) if lam_axis else P()
@@ -174,22 +182,25 @@ class DistributedMatchingObjective:
             else:
                 lam_full = lam
             if ax_mode == "aligned":
-                # shard-local x-carry reduce: only the (E_local,) x vector
-                # is dynamic; the plan's a_dm carries the static weights
+                # shard-local x-carry reduce: only x is dynamic; the slabs
+                # (dual tile) or the plan's a_dm carry the static weights
                 from repro.kernels import ops as kops
                 parts, c_x, x_sq = [], jnp.zeros((), lam_full.dtype), \
                     jnp.zeros((), lam_full.dtype)
                 for slab in slabs:
                     x, c_s, sq_s = objectives.slab_xcarry(
                         slab, lam_full, gamma, kind, iters, pallas)
-                    parts.append(edge_space(x))
+                    parts.append(x)
                     c_x, x_sq = c_x + c_s, x_sq + sq_s
-                local_plan = jax.tree.map(lambda a: a[0], plan)
                 with jax.named_scope(objectives.AX):
-                    ax = kops.ax_aligned_x(local_plan,
-                                           jnp.concatenate(parts),
-                                           use_pallas=pallas,
-                                           out_dtype=lam_full.dtype)
+                    if ax_path == "dual_tile":
+                        ax = kops.ax_dual_tile(slabs, parts, J).astype(
+                            lam_full.dtype)
+                    else:
+                        ax = kops.ax_aligned_x(
+                            jax.tree.map(lambda a: a[0], plan),
+                            jnp.concatenate([edge_space(x) for x in parts]),
+                            use_pallas=pallas, out_dtype=lam_full.dtype)
             elif ax_mode == "aligned_gvals":
                 # shard-local scatter-free reduce over materialized gvals
                 from repro.kernels import ops as kops

@@ -327,7 +327,8 @@ class SolveEngine:
 
     def _start_state(self, tel, lam0, initial_state, total, chunked,
                      adaptive) -> SolveState:
-        """The solve's private initial state; emits `solve_start`.
+        """The solve's private initial state; emits `solve_start`, and
+        the objective's `ax_path` into the run manifest.
 
         The chunk runners donate the state argument (buffer reuse across
         chunks — no double-buffered dual state).  The fresh initial state
@@ -340,6 +341,11 @@ class SolveEngine:
             state = _copy_state(initial_state)
         else:
             state = _copy_state(self.rule.init_state(lam0, config))
+        # the objective's Ax path, where its sweep has a choice of two
+        ax_path = getattr(getattr(self.calculate, "__self__", None),
+                          "ax_path", None)
+        if ax_path is not None:
+            tel.manifest(ax_path=ax_path)
         tel.event("solve_start", algorithm=self.algorithm,
                   iterations_cap=total, chunked=chunked,
                   start_it=(int(jax.device_get(initial_state.it))

@@ -18,14 +18,18 @@ Step 4 is the only non-local stage, and `ax_mode` selects how it runs
                    scatter-add — the paper-faithful baseline);
   "sorted"         edges pre-sorted by destination at construction so the
                    segmented sum takes the `indices_are_sorted` fast path;
-  "aligned"        value-carrying destination-major companion layout
-                   (`AxPlan` with `a_dm`): the plan packs a static copy of
-                   the constraint weights per dual row, so the reduction
-                   consumes the (E,) x vector directly —
-                   `ax[r,k] = Σ_q mask · a_dm[r,q,k] · x[edge_idx[r,q]]` —
-                   and the per-edge gradient tensor (gvals) is never
-                   materialized.  No scatter, no atomics, fixed shapes,
-                   and the only dynamic per-edge HBM traffic is x.
+  "aligned"        the x-carry sweep: the per-edge gradient tensor
+                   (gvals) is never materialized, and Ax is reduced from
+                   x by one of two paths, chosen by `select_ax_path` from
+                   what the objective observes.  On a TPU, with λ
+                   unsharded and a small dual table, one-hot matmuls on
+                   the MXU accumulate each slab straight into an (m, J)
+                   dual tile (`ops.ax_dual_tile`).  Otherwise the
+                   value-carrying destination-major companion layout
+                   (`AxPlan` with `a_dm`) packs a static copy of the
+                   constraint weights per dual row, and
+                   `ax[r,k] = Σ_q mask · a_dm[r,q,k] · x[edge_idx[r,q]]`
+                   is gathered from the (E,) x vector.
   "aligned_gvals"  the index-only aligned layout: gvals are materialized
                    per slab, concatenated to (E, m), and gather-row-summed
                    (the pre-value-carrying lowering, kept as the measured
@@ -33,7 +37,7 @@ Step 4 is the only non-local stage, and `ax_mode` selects how it runs
 
 The legacy gvals-producing sweep survives untouched for
 scatter/sorted/aligned_gvals; "aligned" routes through the gvals-free
-`slab_xcarry` + `ops.ax_aligned_x`.
+`slab_xcarry` + `ops.ax_dual_tile` or `ops.ax_aligned_x`.
 """
 from __future__ import annotations
 
@@ -43,6 +47,8 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.obs import note_ax_path
 
 from . import projections
 from .types import AxPlan, LPData, Slab, dest_gather, edge_space
@@ -56,6 +62,31 @@ LAMBDA_GATHER = "sweep.lambda_gather"   # λ read at each edge, Aᵀλ
 PROJECT = "sweep.project"               # u, x = Π_C(u), cᵀx and ‖x‖²
 AX = "sweep.ax"                         # the Ax reduction by destination
 COLLECTIVE = "sweep.collective"         # the cross-shard sums, g, ∇g, infeas
+
+
+def select_ax_path(platform: str, lambda_axis: Optional[str], m: int,
+                   num_destinations: int) -> str:
+    """How the x-carry sweep reduces Ax (DESIGN.md §3): `dual_tile` on a
+    TPU when λ is not sharded and the dual tile's 3·m·⌈J/128⌉ rows are at
+    most `MAX_TILE_ROWS`, where the one-hot matmuls beat the AxPlan's
+    gathers; `ax_plan` otherwise."""
+    from repro.kernels.ax_dual_tile import MAX_TILE_ROWS, tile_rows
+    if (platform == "tpu" and lambda_axis is None
+            and tile_rows(m, num_destinations) <= MAX_TILE_ROWS):
+        return "dual_tile"
+    return "ax_plan"
+
+
+def platform_of(tree) -> str:
+    """The platform of the first device or placed array in `tree`; the
+    default backend when there is none (numpy arrays, tracers)."""
+    for leaf in jax.tree.leaves(tree):
+        if isinstance(leaf, jax.Device):
+            return leaf.platform
+        if isinstance(leaf, jax.Array) and not isinstance(leaf,
+                                                          jax.core.Tracer):
+            return next(iter(leaf.devices())).platform
+    return jax.default_backend()
 
 
 class ObjectiveAux(NamedTuple):
@@ -281,7 +312,13 @@ class MatchingObjective:
                                     for s in lp.slabs])
             self._perm = jnp.asarray(np.argsort(dests, kind="stable"))
             self._sorted_dest = jnp.asarray(np.sort(dests, kind="stable"))
-        elif ax_mode in ("aligned", "aligned_gvals"):
+        self.ax_path = None
+        self._plan = None
+        if ax_mode == "aligned":
+            self.ax_path = select_ax_path(platform_of(lp), None, lp.m,
+                                          lp.num_destinations)
+            note_ax_path(self.ax_path)
+        if ax_mode == "aligned_gvals" or self.ax_path == "ax_plan":
             if ax_plan is None:
                 from .instance import build_ax_plan
                 ax_plan = build_ax_plan(lp,
@@ -301,25 +338,28 @@ class MatchingObjective:
     @property
     def _carry_x(self) -> bool:
         """True when the sweep is x-only (value-carrying aligned mode):
-        slabs emit (E,)-flattened x parts instead of (E, m) gvals."""
+        slabs emit their (n, w) x instead of (E, m) gvals."""
         return self.ax_mode == "aligned"
 
     def _reduce_ax(self, parts, dtype):
-        """(m, J) Ax from per-slab flattened parts, per the selected mode.
+        """(m, J) Ax from per-slab parts, per the selected mode.
 
-        For the x-carry "aligned" mode `parts` are (n·w,) x vectors (the
-        only dynamic per-edge array — concatenating them is O(E), not
-        O(E·m)); for every gvals mode they are (n·w, m) per-edge gradient
-        values.
+        For the x-carry "aligned" mode `parts` are each slab's (n, w) x
+        (the only dynamic per-edge array): the dual tile reduces them slab
+        by slab, the AxPlan path from their (E,) concatenation.  For every
+        gvals mode they are (n·w, m) per-edge gradient values.
         """
         with jax.named_scope(AX):
             lp = self.lp
             J = lp.num_destinations
             if self.ax_mode == "aligned":
                 from repro.kernels import ops as kops
-                return kops.ax_aligned_x(self._plan, jnp.concatenate(parts),
-                                         use_pallas=self.use_pallas,
-                                         out_dtype=dtype)
+                if self.ax_path == "dual_tile":
+                    return kops.ax_dual_tile(lp.slabs, parts, J).astype(dtype)
+                return kops.ax_aligned_x(
+                    self._plan,
+                    jnp.concatenate([edge_space(x) for x in parts]),
+                    use_pallas=self.use_pallas, out_dtype=dtype)
             if self.ax_mode == "aligned_gvals":
                 from repro.kernels import ops as kops
                 return kops.ax_aligned(self._plan,
@@ -352,7 +392,7 @@ class MatchingObjective:
             if carry:
                 x, c_s, sq_s = slab_xcarry(
                     slab, lam, gamma, kind, iters, self.use_pallas, shift)
-                parts.append(edge_space(x))
+                parts.append(x)
             else:
                 x, gvals, c_s, sq_s = slab_xgvals(
                     slab, lam, gamma, kind, iters, self.use_pallas, shift)

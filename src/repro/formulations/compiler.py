@@ -150,9 +150,9 @@ class ComposedObjective(MatchingObjective):
         the bitwise legacy-parity guarantees) with two generalizations:
         the per-slab shift from the coupling rows, and one weighted-sum
         accumulator per row.  The coupling rows already consume x, so the
-        x-carry aligned mode is free here: collect the (E,) x parts
-        (gvals-free `slab_xcarry` sweep) and reduce through the
-        value-carrying plan.  Keep the sweeps in lockstep when editing
+        x-carry aligned mode is free here: collect each slab's x
+        (gvals-free `slab_xcarry` sweep) and reduce it by the objective's
+        Ax path.  Keep the sweeps in lockstep when editing
         either."""
         parts = []
         c_x = jnp.zeros((), lam.dtype)
@@ -165,7 +165,7 @@ class ComposedObjective(MatchingObjective):
                 x, c_s, sq_s = slab_xcarry(
                     slab, lam, gamma, kind, iters, self.use_pallas,
                     self._shift_for(si, mus))
-                parts.append(edge_space(x))
+                parts.append(x)
             else:
                 x, gvals, c_s, sq_s = slab_xgvals(
                     slab, lam, gamma, kind, iters, self.use_pallas,

@@ -5,13 +5,15 @@ picks interpret mode there.  On any other backend (this CPU container,
 unit tests) they run in interpret mode, which executes the kernel body in
 Python with identical semantics.  The choice follows the platform JAX
 runs on; callers do not make it.  `repro.core.objectives` routes through
-these wrappers when SolveConfig.use_pallas is set.
+these wrappers when SolveConfig.use_pallas is set, and through
+`ax_dual_tile` wherever its Ax path rule picks the dual tile.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from . import ax_dual_tile as _ax_dual_tile
 from . import ax_reduce as _ax_reduce
 from . import dual_grad as _dual_grad
 from . import proj as _proj
@@ -145,3 +147,22 @@ def ax_aligned_x(plan: AxPlan, x: jax.Array, use_pallas: bool = False,
     ax = rows.at[plan.inv_perm].get(                   # (m, J)
         mode="promise_in_bounds").T
     return ax.astype(out_dtype or x.dtype)
+
+
+def ax_dual_tile(slabs, xs, num_destinations: int) -> jax.Array:
+    """(m, J) f32 Ax summed over slabs by one-hot matmuls on the MXU
+    (kernel: ax_dual_tile.py).
+
+    xs: each slab's (n, w) x*(λ).  The edge values v = a·x are formed in
+    f32, the product `ax_aligned_x` forms, with padding slots set to 0
+    whatever x holds there; the fusion that forms them writes the kernel's
+    (m, w, n) edges-on-lanes view.
+    """
+    ax = None
+    for slab, x in zip(slabs, xs):
+        v = jnp.where(slab.mask[..., None], slab.a_vals * x[..., None], 0.0)
+        part = _ax_dual_tile.ax_dual_tile(
+            jnp.transpose(v, (2, 1, 0)), slab.dest_idx.T, num_destinations,
+            interpret=_interpret())
+        ax = part if ax is None else ax + part
+    return ax

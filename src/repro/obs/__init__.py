@@ -15,8 +15,9 @@ sampler (host RSS via procfs, device HBM stats where the backend
 reports them, per-runner compiled estimates) whose watermarks the
 engine stamps into the manifest.
 """
-from .telemetry import (JsonlSink, ListSink, Telemetry, LEVELS, note_op_scopes,
-                        op_scopes, span_totals, spanned)
+from .telemetry import (JsonlSink, ListSink, Telemetry, LEVELS, ax_paths,
+                        note_ax_path, note_op_scopes, op_scopes, span_totals,
+                        spanned)
 from .schema import (EVENT_FIELDS, RunLog, SchemaError, iter_events,
                      load_run, validate_event, validate_run)
 from .profile import ProfilerHook
@@ -30,7 +31,8 @@ from .memory import (MemorySample, MemorySampler, compiled_memory_estimate,
 
 __all__ = [
     "Telemetry", "JsonlSink", "ListSink", "LEVELS",
-    "span_totals", "spanned", "note_op_scopes", "op_scopes",
+    "span_totals", "spanned", "note_op_scopes", "op_scopes", "note_ax_path",
+    "ax_paths",
     "EVENT_FIELDS", "RunLog", "SchemaError", "iter_events", "load_run",
     "validate_event", "validate_run",
     "ProfilerHook",

@@ -61,7 +61,8 @@ import uuid
 from typing import Any, Callable, Dict, List, Optional, TextIO, Tuple
 
 __all__ = ["Telemetry", "JsonlSink", "ListSink", "LEVELS", "span_totals",
-           "spanned", "note_op_scopes", "op_scopes"]
+           "spanned", "note_op_scopes", "op_scopes", "note_ax_path",
+           "ax_paths"]
 
 LEVELS = {"debug": 10, "info": 20, "warning": 30, "error": 40}
 
@@ -72,7 +73,8 @@ try:  # fails soft, as the manifest stamp does: Telemetry never needs jax
 except ImportError:  # pragma: no cover
     from contextlib import nullcontext as _TraceMe
 
-# process-wide tables for readers of a trace (`span_totals`, `op_scopes`)
+# process-wide tables for readers of a trace (`span_totals`, `op_scopes`,
+# `ax_paths`)
 _tables_lock = threading.Lock()
 _totals: Dict[str, List[float]] = {}
 
@@ -126,6 +128,22 @@ def op_scopes() -> Dict[str, str]:
     """Instruction name → innermost scope, over every program noted."""
     with _tables_lock:
         return dict(_op_scopes)
+
+
+_ax_paths: Dict[str, int] = {}
+
+
+def note_ax_path(path: str) -> None:
+    """Count one objective whose x-carry sweep reduces Ax by `path`
+    (`dual_tile` or `ax_plan`, DESIGN.md §3)."""
+    with _tables_lock:
+        _ax_paths[path] = _ax_paths.get(path, 0) + 1
+
+
+def ax_paths() -> Dict[str, int]:
+    """Objectives built in this process so far, by the Ax path they took."""
+    with _tables_lock:
+        return dict(_ax_paths)
 
 
 def _json_safe(v: Any) -> Any:

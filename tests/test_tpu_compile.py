@@ -1,26 +1,32 @@
 """The TPU compiler's verdict on the main path, without a chip.
 
-Compiles — never runs — the five Pallas kernels and one `calculate` of the
-default aligned XLA path for a described TPU v5e chip, at the widths of
-`chip_smoke.py`'s instance (5M sources x 10k destinations x 10 edges per
-source).  Interpret-mode tests cannot see what Mosaic refuses (rank-1
-blocks, in-kernel gathers, VMEM overruns) nor what the XLA TPU backend
-makes of a layout; these compiles can, at a couple of seconds each.
+Compiles — never runs — the six Pallas kernels, one `calculate` of the
+aligned XLA path over the AxPlan, and one of the aligned path on the dual
+tile, for a described TPU v5e chip, at the widths of `chip_smoke.py`'s
+instance (5M sources x 10k destinations x 10 edges per source).
+Interpret-mode tests cannot see what Mosaic refuses (rank-1 blocks,
+in-kernel gathers, VMEM overruns) nor what the XLA TPU backend makes of a
+layout; these compiles can, at a couple of seconds each.
 
 The topology is described inside the fixture, never at import: only one
 process at a time may load the TPU compiler's library, and every test
 worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import MatchingObjective
+from repro.core.distributed import DistributedMatchingObjective
 from repro.core.types import AxBucket, AxPlan, LPData, Slab
-from repro.kernels import ax_reduce, dual_grad, proj
+from repro.kernels import ax_dual_tile, ax_reduce, dual_grad, ops, proj
+from repro.obs import note_op_scopes, op_scopes
 
 J, M = 10_000, 1
 # (rows, width) of the instance's source slabs and destination buckets
@@ -50,6 +56,24 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", enabled)
 
 
+@pytest.fixture(scope="module")
+def plan_path(one_chip):
+    """The aligned `calculate` over the AxPlan, compiled at full size."""
+    S = _sds(one_chip)
+    plan = AxPlan(
+        buckets=tuple(AxBucket(edge_idx=S((r, w), jnp.int32),
+                               mask=S((r, w), jnp.bool_),
+                               dest_ids=S((r,), jnp.int32),
+                               a_dm=S((r, w, M))) for r, w in BUCKETS),
+        inv_perm=S((J,), jnp.int32))
+
+    def calculate(lp, plan, lam, gamma):
+        return MatchingObjective(lp, ax_mode="aligned",
+                                 ax_plan=plan).calculate(lam, gamma)
+
+    return _compile(calculate, _lp(S), plan, S((M, J)), S(()))
+
+
 def _sds(sharding):
     return lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
         shape, dtype, sharding=sharding)
@@ -57,6 +81,16 @@ def _sds(sharding):
 
 def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
+
+
+def _lp(S):
+    return LPData(
+        slabs=tuple(Slab(a_vals=S((n, w, M)), c_vals=S((n, w)),
+                         dest_idx=S((n, w), jnp.int32),
+                         mask=S((n, w), jnp.bool_), ub=S((n, w)),
+                         s=S((n,)), source_ids=S((n,), jnp.int32))
+                    for n, w in SLABS),
+        b=S((M, J)))
 
 
 def _slab_args(S, n, w):
@@ -94,33 +128,68 @@ def test_ax_kernel_compiles(one_chip, kernel, r, w):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_aligned_calculate_compiles_and_fits(one_chip):
-    """The default hot path (XLA, x-carry aligned Ax) at full size: no
-    Mosaic kernel in it, it fits the chip's 16 GB, and its code stays
-    small — a gather through an (n, w) index with a small w, or a
+@pytest.mark.parametrize("n,w", SLABS)
+def test_ax_dual_tile_kernel_compiles(one_chip, n, w):
+    S = _sds(one_chip)
+    c = _compile(lambda v, d: ax_dual_tile.ax_dual_tile(v, d, J),
+                 S((M, w, n)), S((w, n), jnp.int32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_aligned_calculate_compiles_and_fits(plan_path):
+    """The hot path over the AxPlan (XLA, x-carry aligned Ax) at full
+    size: no Mosaic kernel in it, it fits the chip's 16 GB, and its code
+    stays small — a gather through an (n, w) index with a small w, or a
     row-major flatten of such a slab, compiles into code linear in n
     (about 95 MB and two minutes here before `dest_gather`/`edge_space`)."""
-    S = _sds(one_chip)
-    lp = LPData(
-        slabs=tuple(Slab(a_vals=S((n, w, M)), c_vals=S((n, w)),
-                         dest_idx=S((n, w), jnp.int32),
-                         mask=S((n, w), jnp.bool_), ub=S((n, w)),
-                         s=S((n,)), source_ids=S((n,), jnp.int32))
-                    for n, w in SLABS),
-        b=S((M, J)))
-    plan = AxPlan(
-        buckets=tuple(AxBucket(edge_idx=S((r, w), jnp.int32),
-                               mask=S((r, w), jnp.bool_),
-                               dest_ids=S((r,), jnp.int32),
-                               a_dm=S((r, w, M))) for r, w in BUCKETS),
-        inv_perm=S((J,), jnp.int32))
-
-    def calculate(lp, plan, lam, gamma):
-        return MatchingObjective(lp, ax_mode="aligned",
-                                 ax_plan=plan).calculate(lam, gamma)
-
-    c = _compile(calculate, lp, plan, S((M, J)), S(()))
+    c = plan_path
     assert "tpu_custom_call" not in c.as_text()
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
     assert mem.generated_code_size_in_bytes < 48e6
+
+
+def test_dual_tile_calculate_compiles_under_the_plan_path(one_chip,
+                                                          plan_path,
+                                                          monkeypatch):
+    """`DistributedMatchingObjective(ax_mode="aligned")` on a v5e mesh
+    takes the dual tile, with no AxPlan argument.  Mosaic accepts the
+    kernel at every slab width, no one-hot buffer reaches HBM (the
+    (E x 128) one-hot alone would be 17 GB: the temporaries stay under
+    the plan path's), and the kernels' instructions sit under `sweep.ax`
+    in `op_scopes()`, where `ax_ms_per_iter` reads them."""
+    # the described chip is no backend: the kernels would pick interpret
+    # mode from the CPU
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    axes = tuple(mesh.axis_names)
+    rows, rep = NamedSharding(mesh, P(axes)), NamedSharding(mesh, P())
+    lp = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rows),
+        _lp(_sds(one_chip)))
+    lp = lp._replace(b=jax.ShapeDtypeStruct((M, J), jnp.float32,
+                                            sharding=rep))
+    paths = []
+
+    def calculate(lp, lam, gamma):
+        obj = DistributedMatchingObjective(lp=lp, mesh=mesh,
+                                           source_axes=axes,
+                                           ax_mode="aligned")
+        paths.append(obj.ax_path)
+        return obj.calculate(lam, gamma)
+
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
+    c = _compile(calculate, lp, jax.ShapeDtypeStruct(
+        (M, J), jnp.float32, sharding=rep), scalar)
+    assert paths == ["dual_tile"]
+    text = c.as_text()
+    kernels = re.findall(r"^\s*(%\S+) = [^\n]*custom_call_target="
+                         r'"tpu_custom_call"', text, flags=re.M)
+    assert len(kernels) == len(SLABS)
+    mem, plan_mem = c.memory_analysis(), plan_path.memory_analysis()
+    assert mem.temp_size_in_bytes < plan_mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    note_op_scopes(c)
+    scopes = op_scopes()
+    assert {scopes.get(k) for k in kernels} == {"sweep.ax"}

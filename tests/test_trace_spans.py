@@ -184,3 +184,28 @@ def test_op_scopes_name_the_compiled_runner_ops(lp):
     found = set(op_scopes().values())
     assert set(SCOPES) | {"sweep", "sweep.collective"} <= found, found
     assert all(name.startswith("%") for name in op_scopes())
+
+
+def test_dual_tile_kernel_is_noted_under_sweep_ax(lp, monkeypatch):
+    """The dual-tile Ax kernel's instructions carry the `sweep.ax` scope
+    into the compiled runner, so `ax_ms_per_iter` keeps reading the Ax
+    on that path.  The objective takes the dual tile only on a TPU: the
+    test steers it there (on the CPU the kernel runs in interpret mode)."""
+    from repro.core import objectives
+    from repro.obs import note_op_scopes, op_scopes
+    monkeypatch.setattr(objectives, "platform_of", lambda tree: "tpu")
+    obj = MatchingObjective(lp, ax_mode="aligned")
+    assert obj.ax_path == "dual_tile"
+    eng = SolveEngine(obj.calculate, CFG)
+    state = eng.rule.init_state(jnp.zeros(obj.dual_shape, jnp.float32), CFG)
+    hoisted, consts = hoist_constants(obj.calculate, state.lam,
+                                      jnp.float32(0.0))
+    compiled = _make_chunk_runner(hoisted, CFG, eng.rule, 5, True).lower(
+        state, jnp.float32(0.1), consts).compile()
+    note_op_scopes(compiled)
+    kernel = set(re.findall(
+        r'^\s*(?:ROOT\s+)?(%[^\s=]+) = [^\n]*op_name="[^"\n]*'
+        r'jit\(ax_dual_tile\)', compiled.as_text(), flags=re.M))
+    assert kernel
+    scopes = op_scopes()
+    assert {scopes.get(name) for name in kernel} == {"sweep.ax"}
